@@ -146,5 +146,6 @@ if __name__ == "__main__":
         fleet_replay(max(args.fleet, 1), arrival_rate=args.arrivals,
                      policy=args.policy, deal=args.deal, pods=args.pods)
         sys.exit(0)
+    from repro.core.profiles import V5E
     from repro.launch.serve import demo
-    demo()
+    demo(device_kind=V5E)      # plans for a v5e whatever device runs it

@@ -184,15 +184,43 @@ WORKLOADS = {
 }
 
 
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    bf16_flops: float          # FLOP/s
+    hbm_bw: float              # bytes/s
+    hbm_bytes: float
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s per chip).
+V5E = "TPU v5 lite"
+DEVICE_PEAKS = {
+    V5E: DevicePeaks(bf16_flops=197e12, hbm_bw=819e9, hbm_bytes=16e9),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``; a device not in the table is an error."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(DEVICE_PEAKS)}"
+                         ) from None
+
+
 def tpu_profile_from_costs(name: str, flops: float, bytes_hbm: float,
-                           num_blocks: int, *, peak_flops=197e12,
-                           hbm_bw=819e9) -> KernelProfile:
+                           num_blocks: int, *,
+                           device_kind: str = V5E) -> KernelProfile:
     """TPU adaptation: derive the two-resource profile of a jitted step from
-    its compiled cost analysis. The 'memory stall fraction' plays R_m; PUR
-    and MUR are exactly the compute/memory roofline-term utilizations.
+    its compiled cost analysis against ``device_kind``'s peaks. The 'memory
+    stall fraction' plays R_m; PUR and MUR are exactly the compute/memory
+    roofline-term utilizations.
     """
-    t_compute = flops / peak_flops
-    t_memory = bytes_hbm / hbm_bw
+    peaks = device_peaks(device_kind)
+    t_compute = flops / peaks.bf16_flops
+    t_memory = bytes_hbm / peaks.hbm_bw
     total = max(t_compute + t_memory, 1e-12)
     rm = t_memory / total
     pur = t_compute / max(t_compute, t_memory)

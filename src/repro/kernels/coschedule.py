@@ -24,6 +24,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_DEFAULT_SCOPED_VMEM = 16 << 20   # the TPU compiler's default scoped limit
+_VMEM_HEADROOM = 4 << 20          # Mosaic's internal scratch
+
 
 def make_schedule(n_a: int, n_b: int, run_a: int = 1, run_b: int = 1):
     """Interleave n_a matmul tiles and n_b stream blocks in runs of
@@ -78,6 +81,11 @@ def coschedule(a, b, x, *, scale: float = 2.0, run_a: int = 1,
     a: (M, K), b: (K, N) — K is kept unblocked (the MXU-bound op).
     x: (P, Q) streamed in (bx, Q) row-blocks (the HBM-bound op).
     Returns (a @ b, x * scale).
+
+    The scoped-VMEM limit is reckoned from the block footprint: every
+    in/out block is double-buffered, so at the bench shapes (K = Q = 8192,
+    bf16) the kernel holds about 24 MiB, over the chip compiler's 16 MiB
+    default.
     """
     m, k = a.shape
     n = b.shape[1]
@@ -87,6 +95,9 @@ def coschedule(a, b, x, *, scale: float = 2.0, run_a: int = 1,
     n_a, n_b = n_i * n_j, p // bx
     op, ai, bi = make_schedule(n_a, n_b, run_a, run_b)
     grid = (len(op),)
+    blocks = (bm * k * a.dtype.itemsize + k * bn * b.dtype.itemsize
+              + bm * bn * a.dtype.itemsize + 2 * bx * q * x.dtype.itemsize)
+    vmem_limit = max(_DEFAULT_SCOPED_VMEM, 2 * blocks + _VMEM_HEADROOM)
 
     def a_map(t, op_r, ai_r, bi_r):
         return (ai_r[t] // n_j, 0)
@@ -117,6 +128,7 @@ def coschedule(a, b, x, *, scale: float = 2.0, run_a: int = 1,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n_a, bm, bn), a.dtype),
                    jax.ShapeDtypeStruct((p, q), x.dtype)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(jnp.asarray(op), jnp.asarray(ai), jnp.asarray(bi), a, b, x)
     mm = mm_tiles.reshape(n_i, n_j, bm, bn).transpose(0, 2, 1, 3).reshape(m, n)

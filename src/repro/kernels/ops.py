@@ -1,12 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same entry points work in CPU
-tests and on real hardware (set REPRO_PALLAS_INTERPRET=0 on TPU).
+Kernels run in Pallas interpret mode on the CPU backend (tests) and are
+compiled everywhere else; nothing can force interpret mode on a chip.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 
@@ -18,10 +17,7 @@ from repro.kernels import sliced_matmul as _sm
 
 
 def _default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("slice_size", "bm", "bn", "bk"))

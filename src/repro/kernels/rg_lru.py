@@ -1,8 +1,11 @@
 """RG-LRU linear recurrence (Griffin) as a Pallas TPU kernel.
 
 Grid (B, W_blocks, n_chunks); chunks sequential with the hidden state
-carried in VMEM scratch; within a chunk the first-order recurrence is
-computed with an associative scan over the time axis of the block.
+carried in VMEM scratch. Within a chunk the first-order recurrence
+h_t = a_t h_{t-1} + b_t is a log-step (Hillis-Steele) scan over the time
+axis: each step combines every row with the row ``d`` above it, fetched by
+a sublane roll, so the kernel needs no scatter and no associative_scan,
+neither of which Mosaic lowers.
 """
 from __future__ import annotations
 
@@ -25,17 +28,19 @@ def _lru_kernel(x_ref, a_ref, o_ref, h_ref, *, chunk: int):
     a_log = a_ref[0].astype(jnp.float32)             # (C, bw), <= 0
     a = jnp.exp(a_log)
     b = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * a_log), 1e-12)) * x
-    h0 = h_ref[0]                                    # (1, bw) scratch row
-    b = b.at[0].add(a[0] * h0)
+    row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    b = b + jnp.where(row == 0, a * h_ref[...], 0.0)   # carry-in at t = 0
 
-    def combine(c1, c2):
-        a1, b1 = c1
-        a2, b2 = c2
-        return a1 * a2, a2 * b1 + b2
-
-    _, hs = jax.lax.associative_scan(combine, (a, b), axis=0)
-    o_ref[0] = hs.astype(o_ref.dtype)
-    h_ref[0] = hs[-1]
+    d = 1
+    while d < chunk:
+        keep = row >= d
+        a_up = jnp.where(keep, pltpu.roll(a, d, 0), 1.0)
+        b_up = jnp.where(keep, pltpu.roll(b, d, 0), 0.0)
+        b = b + a * b_up
+        a = a * a_up
+        d *= 2
+    o_ref[0] = b.astype(o_ref.dtype)
+    h_ref[...] = b[chunk - 1:chunk]
 
 
 def rg_lru(x, a_log, *, chunk: int = 128, bw: int = 512,

@@ -6,6 +6,7 @@ touches jax device state — the dry-run sets XLA_FLAGS before first init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,8 +15,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes)
 
 
-def make_host_mesh(model_parallel: int = 1):
-    """Small mesh over whatever devices exist (tests / quickstart)."""
-    n = len(jax.devices())
+def make_host_mesh(model_parallel: int = 1, devices=None):
+    """(data, model) mesh over ``devices`` (default: every device)."""
+    devices = list(devices) if devices is not None else jax.devices()
+    n = len(devices)
     model = min(model_parallel, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         devices=devices, axis_types=(AxisType.Auto,) * 2)

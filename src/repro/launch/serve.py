@@ -17,12 +17,14 @@ predicted makespan and warming the shared decision cache (persisted across
 processes via ``REPRO_DECISION_CACHE``) — then dispatches real work with
 the same shared scheduler, so every dispatch-loop decision is a cache hit.
 
-  PYTHONPATH=src python -m repro.launch.serve --demo
+  PYTHONPATH=src python -m repro.launch.serve --demo \
+      [--device-kind "TPU v5 lite"]   # required when no TPU is attached
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 from typing import Callable, Dict, List, Optional
 
@@ -30,14 +32,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config, reduced
+from repro.configs import ModelConfig, get_config, reduced
 from repro.core.engine import LaneSpec, WorkloadEngine, run_fleet
 from repro.core.jobstore import (CANCELLED, FINISHED, PAUSED, QUEUED,
                                  RUNNING, JobStoreError, StaleLease)
 from repro.core.markov import MarkovModel
-from repro.core.profiles import TPU_V5E, KernelProfile, tpu_profile_from_costs
+from repro.core.profiles import (TPU_V5E, V5E, KernelProfile, device_peaks,
+                                 tpu_profile_from_costs)
 from repro.core.simulator import IPCTable
 from repro.data.synthetic import make_batch, poisson_arrivals
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 
 
@@ -49,44 +53,82 @@ class Job:
     num_slices: int             # microbatch slices pending
     batch_per_slice: int = 2
     seq: int = 64
+    published: bool = False     # the arch's published widths, else the
+                                # reduced CPU-smoke config
+
+
+def prefill_logits(params, batch, *, cfg):
+    """One prefill slice: logits over the whole prompt."""
+    return T.forward(params, cfg, batch)[0]
+
+
+def decode_logits(params, caches, tok, t, *, cfg):
+    """One decode slice: next-token logits at position ``t``."""
+    return T.decode_step(params, cfg, caches, tok, t)[0]
 
 
 class SharedPodServer:
-    """Kernelet executor over a queue of tenant jobs."""
+    """Kernelet executor over a queue of tenant jobs.
 
-    def __init__(self, *, gpu_spec=TPU_V5E, seed: int = 0):
+    ``device_kind`` names the chip the scheduler plans for (a key of
+    ``repro.core.profiles.DEVICE_PEAKS``). On a TPU it defaults to the
+    attached chip; on any other backend it must be given."""
+
+    def __init__(self, *, gpu_spec=TPU_V5E, seed: int = 0,
+                 device_kind: Optional[str] = None):
+        if device_kind is None:
+            if jax.default_backend() != "tpu":
+                raise ValueError(
+                    f"no TPU attached (backend {jax.default_backend()!r}): "
+                    f"name the device to plan for, e.g. "
+                    f"device_kind={V5E!r}")
+            device_kind = jax.devices()[0].device_kind
+        device_peaks(device_kind)          # unknown kinds fail here
+        use_compile_cache()
+        self.device_kind = device_kind
         self.spec = gpu_spec
         self.model = MarkovModel(gpu_spec.virtual(), three_state=True)
         self.jobs: Dict[str, Job] = {}
         self.profiles: Dict[str, KernelProfile] = {}
         self._exec: Dict[str, Callable] = {}
         self._args: Dict[str, tuple] = {}
+        self._weights: Dict[ModelConfig, dict] = {}
+        self.outputs: Dict[str, jax.Array] = {}
+        self.compile_s: Dict[str, float] = {}
         self.key = jax.random.PRNGKey(seed)
         self.log: List[tuple] = []
         self._plan_truth: Optional[IPCTable] = None
 
+    def weights(self, cfg: ModelConfig) -> dict:
+        """The one weight copy every tenant of ``cfg`` shares, initialised
+        on the device by a jitted init (no whole-model f32 copy)."""
+        if cfg not in self._weights:
+            init = jax.jit(T.init_params, static_argnums=0)
+            self._weights[cfg] = init(cfg, self.key)
+        return self._weights[cfg]
+
     # ---- job admission: build, profile, register ---- #
     def submit(self, job: Job):
-        cfg = reduced(get_config(job.arch))
-        params = T.init_params(cfg, self.key)
+        cfg = get_config(job.arch)
+        if not job.published:
+            cfg = reduced(cfg)
+        params = self.weights(cfg)
         raw = make_batch(cfg, job.batch_per_slice, job.seq)
         if job.phase == "decode":
-            caches = T.init_decode_caches(cfg, job.batch_per_slice, job.seq)
-            tok = jnp.asarray(raw["tokens"][:, 0])
-
-            def run(params=params, cfg=cfg, caches=caches, tok=tok):
-                logits, _ = T.decode_step(params, cfg, caches, tok,
-                                          jnp.int32(job.seq // 2))
-                return logits
+            step = functools.partial(decode_logits, cfg=cfg)
+            args = (params,
+                    T.init_decode_caches(cfg, job.batch_per_slice, job.seq),
+                    jnp.asarray(raw["tokens"][:, 0]),
+                    jnp.int32(job.seq // 2))
         else:
-            batch = {k: jnp.asarray(v) for k, v in raw.items()
-                     if k != "labels"}
-
-            def run(params=params, cfg=cfg, batch=batch):
-                logits, _, _ = T.forward(params, cfg, batch)
-                return logits
-        jitted = jax.jit(run)
-        jitted.lower().compile()           # executable for the dispatcher
+            step = functools.partial(prefill_logits, cfg=cfg)
+            args = (params, {k: jnp.asarray(v) for k, v in raw.items()
+                             if k != "labels"})
+        # weights, caches and inputs are arguments, never constants folded
+        # into the program
+        t0 = time.perf_counter()
+        compiled = jax.jit(step).lower(*args).compile()
+        self.compile_s[job.name] = time.perf_counter() - t0
         # profile at FULL scale: the tenant's real job is the full config
         # on the production pod; its analytic FLOPs/bytes give the PUR/MUR
         # the scheduler reasons about (reduced-config compiled costs would
@@ -99,13 +141,14 @@ class SharedPodServer:
         cost = cell_cost(full_cfg, shape)
         prof = tpu_profile_from_costs(
             job.name, cost["flops"], cost["hbm_bytes"],
-            num_blocks=job.num_slices)
+            num_blocks=job.num_slices, device_kind=self.device_kind)
         # slice-level book-keeping: one block == one microbatch slice
         prof = dataclasses.replace(prof, insns_per_block=1000.0,
                                    num_blocks=job.num_slices)
         self.jobs[job.name] = job
         self.profiles[job.name] = prof
-        self._exec[job.name] = jitted
+        self._exec[job.name] = compiled
+        self._args[job.name] = args
         self.log.append(("submit", job.name, prof.pur, prof.mur, prof.rm))
 
     # ---- engine-backed planning ---- #
@@ -344,7 +387,8 @@ class SharedPodServer:
             if cs.k2 is None:
                 n_run = min(self.jobs[cs.k1].num_slices, 8)
                 for _ in range(n_run):
-                    self._exec[cs.k1]().block_until_ready()
+                    self.outputs[cs.k1] = self._run(cs.k1)
+                    self.outputs[cs.k1].block_until_ready()
                 self.jobs[cs.k1].num_slices -= n_run
                 executed.append((cs.k1, None, n_run, 0, 0.0))
                 continue
@@ -355,13 +399,13 @@ class SharedPodServer:
             outs = []
             n1 = min(r1, j1.num_slices)
             n2 = min(r2, j2.num_slices)
-            for _ in range(max(n1, n2)):
-                if n1 > 0:
-                    outs.append(self._exec[cs.k1]())
-                if n2 > 0:
-                    outs.append(self._exec[cs.k2]())
-            for o in outs:
-                o.block_until_ready()
+            for i in range(max(n1, n2)):
+                if i < n1:
+                    outs.append((cs.k1, self._run(cs.k1)))
+                if i < n2:
+                    outs.append((cs.k2, self._run(cs.k2)))
+            for name, o in outs:
+                self.outputs[name] = o.block_until_ready()
             j1.num_slices -= n1
             j2.num_slices -= n2
             executed.append((cs.k1, cs.k2, n1, n2, cs.cp))
@@ -384,6 +428,9 @@ class SharedPodServer:
                 out["state"] = "lost"
         return out
 
+    def _run(self, name: str):
+        return self._exec[name](*self._args[name])
+
     def _predicted_gain(self, executed) -> float:
         """Aggregate modeled co-scheduling profit over executed rounds."""
         cps, weights = [], []
@@ -396,8 +443,8 @@ class SharedPodServer:
         return float(np.average(cps, weights=weights))
 
 
-def demo():
-    server = SharedPodServer()
+def demo(device_kind: Optional[str] = None):
+    server = SharedPodServer(device_kind=device_kind)
     server.submit(Job("tenantA-phi3-prefill", "phi3-mini-3.8b", "prefill", 24))
     server.submit(Job("tenantB-dsv2-decode", "deepseek-v2-236b", "decode", 24))
     server.submit(Job("tenantC-rwkv-prefill", "rwkv6-1.6b", "prefill", 16))
@@ -421,5 +468,7 @@ def demo():
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--demo", action="store_true")
-    ap.parse_args()
-    demo()
+    ap.add_argument("--device-kind", default=None,
+                    help="chip to plan for (default: the attached TPU; "
+                         f"required off-TPU, e.g. {V5E!r})")
+    demo(ap.parse_args().device_kind)

@@ -2,7 +2,9 @@
 
 Builds the model for ``--arch`` (full or reduced config), shards it on the
 available mesh, and runs the resilient training loop (checkpoint/restart,
-straggler-aware slicing hooks). On this CPU container use ``--reduced``.
+straggler-aware slicing hooks). Params and optimizer state are created on
+the devices with ``param_shardings``, the batch is placed with
+``batch_shardings``. On a CPU host use ``--reduced``.
 
   PYTHONPATH=src python -m repro.launch.train --arch phi3-mini-3.8b \
       --reduced --steps 50 --batch 8 --seq 128
@@ -10,13 +12,16 @@ straggler-aware slicing hooks). On this CPU container use ``--reduced``.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
 import time
+from typing import Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config, reduced
 from repro.data.synthetic import SyntheticLoader
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models import sharding as SH
@@ -33,32 +38,67 @@ def build(arch: str, use_reduced: bool, opt_cfg=None):
     return cfg, opt_cfg
 
 
+class _NoCheckpoints:
+    """Checkpoint store that keeps nothing: a restart begins again from
+    the initial state."""
+
+    @staticmethod
+    def save(directory, step, state):
+        pass
+
+    @staticmethod
+    def latest_step(directory):
+        return None
+
+
 def train(arch: str = "phi3-mini-3.8b", *, use_reduced: bool = True,
           steps: int = 20, batch: int = 8, seq: int = 128,
-          ckpt_dir: str = "artifacts/ckpt", model_parallel: int = 1,
-          seed: int = 0, fail_at=None, log_every: int = 5,
-          compress_grads: bool = False):
+          ckpt_dir: Optional[str] = "artifacts/ckpt",
+          model_parallel: int = 1, seed: int = 0, fail_at=None,
+          log_every: int = 5, compress_grads: bool = False,
+          num_layers: Optional[int] = None,
+          devices: Optional[Sequence] = None):
+    """``num_layers`` cuts the depth of the config (widths stay);
+    ``devices`` (default: all) spans the mesh; ``ckpt_dir=None`` trains
+    without checkpoints."""
+    use_compile_cache()
     cfg, opt_cfg = build(arch, use_reduced,
                          adamw.OptConfig(warmup_steps=10, total_steps=steps,
                                          compress_grads=compress_grads))
-    mesh = make_host_mesh(model_parallel)
+    if num_layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
+    mesh = make_host_mesh(model_parallel, devices)
     with mesh, SH.use_mesh(mesh):
-        params = T.init_params(cfg, jax.random.PRNGKey(seed))
-        opt_state = adamw.init(opt_cfg, params)
-        step_fn_raw = jax.jit(make_train_step(cfg, opt_cfg))
+        key = jax.random.PRNGKey(seed)
+        p_shard = SH.param_shardings(
+            jax.eval_shape(functools.partial(T.init_params, cfg), key), mesh)
+        params = jax.jit(T.init_params, static_argnums=0,
+                         out_shardings=p_shard)(cfg, key)
+        o_shard = SH.param_shardings(
+            jax.eval_shape(functools.partial(adamw.init, opt_cfg), params),
+            mesh)
+        opt_state = jax.jit(adamw.init, static_argnums=0,
+                            out_shardings=o_shard)(opt_cfg, params)
         loader = SyntheticLoader(cfg, batch, seq, seed=seed)
+        b_shard = SH.batch_shardings(loader.load(0), mesh)
+        step_fn_raw = jax.jit(make_train_step(cfg, opt_cfg),
+                              in_shardings=(p_shard, o_shard, b_shard),
+                              out_shardings=(p_shard, o_shard, None),
+                              donate_argnums=(0, 1))
 
         history = []
 
         def step_fn(state, np_batch):
             params, opt_state = state
-            jbatch = {k: jnp.asarray(v) for k, v in np_batch.items()}
+            jbatch = jax.device_put(np_batch, b_shard)
             params, opt_state, metrics = step_fn_raw(params, opt_state, jbatch)
             history.append(float(metrics["loss"]))
             return (params, opt_state), metrics
 
         loop = ResilientLoop(step_fn, (params, opt_state), loader,
-                             ckpt_dir, ckpt_every=max(steps // 4, 5))
+                             ckpt_dir, ckpt_every=max(steps // 4, 5),
+                             store=_NoCheckpoints if ckpt_dir is None
+                             else None)
         t0 = time.time()
         (params, opt_state), end_step = loop.run(steps, fail_at=fail_at)
         dt = time.time() - t0

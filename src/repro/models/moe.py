@@ -136,7 +136,6 @@ def moe_ffn_ep_sharded(x, p, cfg, mesh):
     expert shard and back (production EP — replaces GSPMD-inferred gathers).
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.models.sharding import dp_axes
     dp = dp_axes(mesh)
@@ -157,9 +156,9 @@ def moe_ffn_ep_sharded(x, p, cfg, mesh):
         axes = tuple(a for a in ("pod", "data", "model") if a in mesh.shape)
         return out, jax.lax.pmean(aux, axes)
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         inner, mesh=mesh, in_specs=(x_spec, p_specs),
-        out_specs=(x_spec, P()), check_rep=False)(x, p)
+        out_specs=(x_spec, P()), check_vma=False)(x, p)
     return out, aux
 def _quant_rows(x):
     """Per-row symmetric int8 quantization: (q int8, scales f32)."""

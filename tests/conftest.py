@@ -2,23 +2,16 @@
 
 The tier-1 suite's floor is XLA compile time for the 10 arch smoke tests;
 caching compiled executables on disk (content-addressed by jax itself) cuts
-repeat runs roughly in half. Same env convention as the IPC cache:
-``REPRO_JAX_CACHE=<dir>`` relocates it, ``REPRO_JAX_CACHE=0`` disables.
+repeat runs roughly in half. The cache goes where
+``repro.launch.compile_cache`` puts it: ``JAX_COMPILATION_CACHE_DIR`` if
+set, else ``<checkout>/artifacts/jax_cache``.
 """
-import os
+try:
+    import jax
 
-
-def _setup_jax_cache():
-    path = os.environ.get("REPRO_JAX_CACHE",
-                          os.path.join("artifacts", "jax_cache"))
-    if path.strip().lower() in ("", "0", "off", "none", "disable"):
-        return
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:            # older jax without the knobs: run uncached
-        pass
-
-
-_setup_jax_cache()
+    from repro.launch.compile_cache import use_compile_cache
+except ImportError:          # the numpy-only CI lanes install no jax
+    pass
+else:
+    use_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

@@ -1,0 +1,107 @@
+"""Compiles the main path for a described TPU v5e chip, none attached.
+
+Each Pallas kernel at real widths, and the full-width phi3-mini-3.8b decode
+step with its weights as arguments, go through the chip's own compiler:
+what it refuses (unaligned blocks, unlowerable primitives, scoped-VMEM
+overruns, programs larger than HBM) fails here, where interpret mode would
+pass. Kernels are called with ``interpret=False`` directly, because the
+``ops`` wrappers see the CPU backend and would interpret.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core.profiles import V5E, device_peaks
+from repro.kernels import coschedule as cs
+from repro.kernels import flash_attention as fa
+from repro.kernels import rg_lru as lru
+from repro.kernels import rwkv6_scan as wkv
+from repro.kernels import sliced_matmul as sm
+from repro.launch import serve
+from repro.models import transformer as T
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2 host. The persistent compile cache
+    is off meanwhile: an entry compiled for a described chip cannot be read
+    back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("TPU_LOG_DIR", "disabled")
+            try:
+                topo = topologies.get_topology_desc(platform="tpu",
+                                                    topology_name="v5e:2x2")
+            except Exception as e:
+                pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+            assert topo.devices[0].device_kind == V5E
+            yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+def _kernel_case(name):
+    """(function, argument shapes) of one kernel at the widths it runs."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    if name == "coschedule":      # the fused-interleave bench shapes
+        return (lambda a, b, x: cs.coschedule(a, b, x),
+                [((8192, 8192), bf16), ((8192, 8192), bf16),
+                 ((65536, 8192), bf16)])
+    if name == "sliced_matmul":
+        return (lambda a, b: sm.sliced_matmul(a, b, slice_size=64),
+                [((4096, 4096), bf16)] * 2)
+    if name == "flash_attention":  # phi3-mini head_dim
+        return (lambda q, k, v: fa.flash_attention(q, k, v),
+                [((1, 32, 4096, 96), bf16)] * 3)
+    if name == "rwkv6_scan":
+        return (lambda r, k, v, w, u: wkv.rwkv6_scan(r, k, v, w, u),
+                [((1, 2048, 32, 64), bf16)] * 3
+                + [((1, 2048, 32, 64), f32), ((32, 64), f32)])
+    if name == "rg_lru":
+        return (lambda x, a: lru.rg_lru(x, a),
+                [((1, 2048, 4096), f32)] * 2)
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", ["coschedule", "sliced_matmul",
+                                  "flash_attention", "rwkv6_scan", "rg_lru"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = _kernel_case(name)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_phi3_decode_step_compiles_for_v5e(one_chip):
+    cfg = get_config("phi3-mini-3.8b")
+    batch, max_len = 8, 512
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(T.init_params, cfg),
+                                    jax.random.PRNGKey(0)))
+    caches = on_chip(jax.eval_shape(functools.partial(
+        T.init_decode_caches, cfg, batch, max_len)))
+    tok = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    step = functools.partial(serve.decode_logits, cfg=cfg)
+    compiled = jax.jit(step).lower(params, caches, tok, t).compile()
+    mem = compiled.memory_analysis()
+    weights = 2 * cfg.param_count()                   # bf16
+    assert mem.argument_size_in_bytes >= weights      # weights are arguments
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < device_peaks(V5E).hbm_bytes

@@ -30,7 +30,8 @@ except ImportError:                         # the == pins below still run
 from repro.core import markov
 from repro.core.engine import (_SERVICE_MEMO, DealPolicy, LeastBacklogDeal,
                                WorkloadEngine, aggregate_latency, run_fleet)
-from repro.core.profiles import C2050, GPUSpec, KernelProfile, content_digest
+from repro.core.profiles import (V5E, C2050, GPUSpec, KernelProfile,
+                                 content_digest)
 from repro.core.queue import run_policy_reference
 from repro.core.scheduler import _decision_store_at
 from repro.core.simulator import IPCTable
@@ -235,7 +236,7 @@ def test_service_predictor_memoized_across_assigns(profiles, monkeypatch,
 def test_plan_fleet_second_call_does_no_extra_solves(profiles, monkeypatch,
                                                      no_persist):
     serve = pytest.importorskip("repro.launch.serve")
-    srv = serve.SharedPodServer(gpu_spec=GPU)
+    srv = serve.SharedPodServer(gpu_spec=GPU, device_kind=V5E)
     for i, (name, p) in enumerate(sorted(profiles.items())):
         srv.jobs[name] = serve.Job(name, "arch", "prefill", 2 + i)
         srv.profiles[name] = p

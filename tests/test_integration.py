@@ -1,7 +1,10 @@
 """Integration tests: dry-run machinery on a small mesh, collective parsing,
 scheduler -> fused-kernel handoff, serving queue, analytic cost sanity."""
 import dataclasses
+import functools
 import json
+import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +14,7 @@ import pytest
 from repro.configs import (DECODE_32K, SHAPES, TRAIN_4K, get_config, reduced,
                            applicable_shapes)
 from repro.core.costs import cell_cost, model_flops_fwd
+from repro.core.profiles import V5E
 from repro.launch.dryrun import collective_bytes
 
 
@@ -135,7 +139,7 @@ def test_workload_replay_policies():
 
 def test_serving_queue_drains():
     from repro.launch.serve import Job, SharedPodServer
-    srv = SharedPodServer()
+    srv = SharedPodServer(device_kind=V5E)
     srv.submit(Job("a-prefill", "phi3-mini-3.8b", "prefill", 6, 1, 32))
     srv.submit(Job("b-decode", "starcoder2-15b", "decode", 6, 1, 32))
     res = srv.drain()
@@ -151,18 +155,18 @@ def test_serve_drain_through_daemon(tmp_path):
     from repro.core.jobstore import CANCELLED, FINISHED, PAUSED
     from repro.launch.serve import Job, SharedPodServer
     from repro.runtime.daemon import ServingDaemon
-    srv = SharedPodServer()
+    srv = SharedPodServer(device_kind=V5E)
     srv.submit(Job("a-prefill", "phi3-mini-3.8b", "prefill", 12, 1, 32))
     srv.submit(Job("b-decode", "starcoder2-15b", "decode", 12, 1, 32))
     dmn = ServingDaemon(str(tmp_path / "serve.sqlite"))
     calls = []
     orig = srv._exec["a-prefill"]
 
-    def pause_after_first_slice():
+    def pause_after_first_slice(*args):
         calls.append(1)
         if len(calls) == 1:
             dmn.pause("serve-drain")
-        return orig()
+        return orig(*args)
 
     srv._exec["a-prefill"] = pause_after_first_slice
     res = srv.drain(daemon=dmn, plan_first=False)
@@ -187,6 +191,92 @@ def test_serve_drain_through_daemon(tmp_path):
     dmn.cancel("serve-drain-2")
     assert dmn.store.state("serve-drain-2") == CANCELLED
     dmn.close()
+
+
+def _two_phi3_tenants():
+    from repro.launch.serve import Job, SharedPodServer
+    srv = SharedPodServer(device_kind=V5E)
+    srv.submit(Job("p", "phi3-mini-3.8b", "prefill", 3, 1, 32))
+    srv.submit(Job("d", "phi3-mini-3.8b", "decode", 3, 2, 32))
+    return srv
+
+
+def test_tenants_of_one_arch_share_one_weight_copy():
+    from repro.launch.serve import Job
+    srv = _two_phi3_tenants()
+    assert srv._args["p"][0] is srv._args["d"][0]
+    srv.submit(Job("s", "starcoder2-15b", "decode", 1, 1, 32))
+    assert srv._args["s"][0] is not srv._args["p"][0]
+    assert len(srv._weights) == 2
+
+
+def test_submitted_steps_take_weights_as_arguments():
+    """No constant in a compiled tenant step is as large as a weight
+    matrix: the weights reach the program as arguments, not folded-in
+    literals."""
+    import re
+    srv = _two_phi3_tenants()
+    smallest = reduced(get_config("phi3-mini-3.8b")).d_model ** 2
+    for name in ("p", "d"):
+        hlo = srv._exec[name].as_text()
+        shapes = re.findall(r"\w+\[([\d,]*)\]\{[^}]*\} constant\(", hlo)
+        consts = [np.prod([int(d) for d in dims.split(",") if d])
+                  for dims in shapes]
+        assert max(consts, default=0) < smallest, (name, max(consts))
+
+
+def test_drain_outputs_match_each_step_run_alone():
+    from repro.launch.serve import decode_logits, prefill_logits
+    srv = _two_phi3_tenants()
+    srv.drain(plan_first=False)
+    assert all(j.num_slices == 0 for j in srv.jobs.values())
+    cfg = reduced(get_config("phi3-mini-3.8b"))
+    for name, step in (("p", prefill_logits), ("d", decode_logits)):
+        want = jax.jit(functools.partial(step, cfg=cfg))(*srv._args[name])
+        got = srv.outputs[name]
+        assert got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=2e-2, rtol=2e-2)
+
+
+def test_server_needs_a_known_planning_device():
+    from repro.launch.serve import SharedPodServer
+    with pytest.raises(ValueError, match="no TPU attached"):
+        SharedPodServer()
+    with pytest.raises(ValueError, match="no published peaks"):
+        SharedPodServer(device_kind="TPU v0")
+
+
+def test_compile_cache_location(tmp_path):
+    """Without JAX_COMPILATION_CACHE_DIR the cache is one absolute path
+    from any working directory; with it, compiles land there."""
+    import subprocess
+    import sys
+    from repro.launch import compile_cache
+    src = str(pathlib.Path(compile_cache.__file__).resolve().parents[2])
+    code = ("import jax; from repro.launch.compile_cache import "
+            "use_compile_cache as u; print(u()); "
+            "jax.jit(lambda x: x + 1)(1.0).block_until_ready(); "
+            "print(jax.config.jax_compilation_cache_dir)")
+    env = {k: v for k, v in os.environ.items()
+           if k != compile_cache.ENV_VAR}
+    env.update(PYTHONPATH=src, JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+
+    def run(cwd, **extra):
+        out = subprocess.run([sys.executable, "-c", code], cwd=cwd,
+                             env=dict(env, **extra), capture_output=True,
+                             text=True, timeout=120, check=True)
+        return out.stdout.split()
+
+    default = compile_cache.DEFAULT_DIR
+    assert os.path.isabs(default)
+    assert default.endswith(os.path.join("artifacts", "jax_cache"))
+    assert run(tmp_path) == run(src) == [default, default]
+    mine = str(tmp_path / "cache")
+    assert run(tmp_path, JAX_COMPILATION_CACHE_DIR=mine) == [mine, mine]
+    assert os.listdir(mine)
 
 
 def test_structural_collective_accounting():
