@@ -11,6 +11,14 @@ slices on the shared mesh. On TPU the fused path is
 for correctness and the co-scheduling profit is reported from the
 TPU-adapted Markov model.
 
+Every admitted job keeps a record (``JobRecord``): when it was admitted,
+when its first slice was enqueued, and when the wait that covered its
+last slice returned; ``drain()`` returns the records of the jobs it
+completed. The drain's stages are ``jax.profiler.TraceAnnotation`` spans
+named ``kernelet.<stage>``, on the profiler's clock, so a trace says
+which stage held the chip idle; with no profiler running a span costs
+about a microsecond.
+
 Scheduling runs on the workload engine (``repro.core.engine``): the server
 first *plans* the drain as a simulated engine replay lane — yielding the
 predicted makespan and warming the shared decision cache (persisted across
@@ -23,10 +31,11 @@ the same shared scheduler, so every dispatch-loop decision is a cache hit.
 from __future__ import annotations
 
 import argparse
+import collections
 import dataclasses
 import functools
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Deque, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +64,32 @@ class Job:
     seq: int = 64
     published: bool = False     # the arch's published widths, else the
                                 # reduced CPU-smoke config
+
+
+@dataclasses.dataclass
+class JobRecord:
+    """One admitted job of a tenant, in ``time.perf_counter`` seconds:
+    admitted at ``admitted_at``; its first slice enqueued at
+    ``first_dispatch``; ``done`` when the wait that covered its last
+    slice returned. ``left`` counts its slices not yet enqueued."""
+    job_id: int
+    tenant: str
+    slices: int
+    admitted_at: float
+    first_dispatch: Optional[float] = None
+    done: Optional[float] = None
+    left: int = 0
+
+
+def _span(stage: str, **args):
+    """The profiler span ``kernelet.<stage>``, carrying ``args``."""
+    return jax.profiler.TraceAnnotation(f"kernelet.{stage}", **args)
+
+
+def _named(step: Callable, name: str) -> Callable:
+    """``step`` under ``name``, which names its program on the device."""
+    step.__name__ = name
+    return step
 
 
 def prefill_logits(params, batch, *, cfg):
@@ -96,7 +131,10 @@ class SharedPodServer:
         self.outputs: Dict[str, jax.Array] = {}
         self.compile_s: Dict[str, float] = {}
         self.key = jax.random.PRNGKey(seed)
-        self.log: List[tuple] = []
+        # per tenant, the admitted jobs not yet completed, oldest first
+        self._queue: Dict[str, Deque[JobRecord]] = {}
+        self._next_job = 0
+        self._drains = 0
         self._plan_truth: Optional[IPCTable] = None
 
     def weights(self, cfg: ModelConfig) -> dict:
@@ -115,13 +153,15 @@ class SharedPodServer:
         params = self.weights(cfg)
         raw = make_batch(cfg, job.batch_per_slice, job.seq)
         if job.phase == "decode":
-            step = functools.partial(decode_logits, cfg=cfg)
+            step = _named(functools.partial(decode_logits, cfg=cfg),
+                          "decode_step")
             args = (params,
                     T.init_decode_caches(cfg, job.batch_per_slice, job.seq),
                     jnp.asarray(raw["tokens"][:, 0]),
                     jnp.int32(job.seq // 2))
         else:
-            step = functools.partial(prefill_logits, cfg=cfg)
+            step = _named(functools.partial(prefill_logits, cfg=cfg),
+                          "prefill_step")
             args = (params, {k: jnp.asarray(v) for k, v in raw.items()
                              if k != "labels"})
         # weights, caches and inputs are arguments, never constants folded
@@ -149,7 +189,26 @@ class SharedPodServer:
         self.profiles[job.name] = prof
         self._exec[job.name] = compiled
         self._args[job.name] = args
-        self.log.append(("submit", job.name, prof.pur, prof.mur, prof.rm))
+        self._queue[job.name] = collections.deque()
+        if job.num_slices > 0:
+            self._record(job.name, job.num_slices)
+
+    def admit(self, tenant: str, slices: int) -> int:
+        """Add a job of ``slices`` slices to the submitted ``tenant``;
+        returns its job id. Slices added to ``jobs[tenant].num_slices``
+        directly are served alike but belong to no job record."""
+        if tenant not in self._queue:
+            raise KeyError(f"no submitted tenant {tenant!r}")
+        if slices < 1:
+            raise ValueError(f"a job needs a slice, got {slices}")
+        self.jobs[tenant].num_slices += slices
+        return self._record(tenant, slices)
+
+    def _record(self, tenant: str, slices: int) -> int:
+        job_id, self._next_job = self._next_job, self._next_job + 1
+        self._queue[tenant].append(JobRecord(
+            job_id, tenant, slices, time.perf_counter(), left=slices))
+        return job_id
 
     # ---- engine-backed planning ---- #
     def plan(self, engine: WorkloadEngine, *, rounds: int = 1500) -> dict:
@@ -330,13 +389,20 @@ class SharedPodServer:
               arrival_rate: Optional[float] = None,
               slo_deadline: Optional[float] = None,
               plan_policy: str = "KERNELET", daemon=None,
-              job_name: str = "serve-drain"):
+              job_name: str = "serve-drain") -> dict:
         """Dispatch every pending job. ``arrival_rate`` switches the
         planning stage to the arrival-timed replay (``plan_arrivals``), so
         the returned plan carries predicted queue-wait/SLO metrics for the
         drain the dispatcher is about to execute; ``plan_policy`` selects
         the planning policy (e.g. ``"EDF-KERNELET"`` for a deadline-aware
         plan).
+
+        Returns the ``rounds`` run (``(k1, k2, n1, n2, cp)``, ``k2`` None
+        for a tenant run alone), ``wall_s`` from the first dispatch to the
+        end, ``jobs``: the ``JobRecord`` of every job completed, each
+        done, ``returned_at``: the drain's return, and the plan's coverage:
+        ``planned_slices`` (the slices the plan simulated, 0 without a
+        plan) of ``pending_slices`` (the slices pending at the start).
 
         ``daemon`` (a ``repro.runtime.daemon.ServingDaemon``) routes the
         drain through the durable job path: the dispatch runs under a
@@ -347,6 +413,13 @@ class SharedPodServer:
         ``job_name`` resumes it. The result gains ``job_id`` and
         ``state`` (``finished`` / ``cancelled`` / ``paused`` /
         ``"lost"`` if the lease was stolen)."""
+        self._drains += 1
+        with _span("drain", seq=self._drains):
+            return self._drain(max_rounds, plan_first, arrival_rate,
+                               slo_deadline, plan_policy, daemon, job_name)
+
+    def _drain(self, max_rounds, plan_first, arrival_rate, slo_deadline,
+               plan_policy, daemon, job_name) -> dict:
         # fail fast with a clear message, not a KeyError mid-dispatch: a
         # pending job must have completed submit() (profile + executable)
         missing = sorted(n for n, j in self.jobs.items() if j.num_slices > 0
@@ -356,77 +429,126 @@ class SharedPodServer:
                 f"pending jobs with no registered profile/executable: "
                 f"{missing} — submit() must complete for every job "
                 "before drain()")
-        engine = WorkloadEngine()
-        sched = engine.scheduler_for(self.spec, self.profiles,
-                                     alpha_p=0.2, alpha_m=0.2, cp_margin=0.0)
-        plan = None
-        if plan_first:
-            plan = (self.plan_arrivals(engine, arrival_rate,
-                                       slo_deadline=slo_deadline,
-                                       policy=plan_policy)
-                    if arrival_rate is not None else self.plan(engine))
+        pending = {n: j.num_slices for n, j in self.jobs.items()
+                   if j.num_slices > 0}
+        # the plan replays each pending tenant's profile, whose num_blocks
+        # is fixed at submit, whatever is pending now
+        counts = {"planned_slices": sum(self.profiles[n].num_blocks
+                                        for n in pending)
+                  if plan_first else 0,
+                  "pending_slices": sum(pending.values())}
+        with _span("plan", planned=counts["planned_slices"],
+                   pending=counts["pending_slices"]):
+            engine = WorkloadEngine()
+            sched = engine.scheduler_for(self.spec, self.profiles,
+                                         alpha_p=0.2, alpha_m=0.2,
+                                         cp_margin=0.0)
+            plan = None
+            if plan_first:
+                plan = (self.plan_arrivals(engine, arrival_rate,
+                                           slo_deadline=slo_deadline,
+                                           policy=plan_policy)
+                        if arrival_rate is not None else self.plan(engine))
         jid = fence = None
         if daemon is not None:
             jid, fence = self._register_drain_job(daemon, job_name,
                                                   plan_policy)
-        t0 = time.time()
-        executed = []
+        t0 = time.perf_counter()
+        executed, completed = [], []
+
+        def result(**extra) -> dict:
+            end = time.perf_counter()
+            return {"rounds": executed, "wall_s": end - t0,
+                    "predicted_gain": self._predicted_gain(executed),
+                    "plan": plan, "jobs": completed, "returned_at": end,
+                    **counts, **extra}
+
         while any(j.num_slices > 0 for j in self.jobs.values()):
-            if daemon is not None:
-                stopped = self._drain_control(daemon, jid, fence,
-                                              len(executed))
-                if stopped is not None:
-                    return {"rounds": executed,
-                            "wall_s": time.time() - t0,
-                            "predicted_gain":
-                                self._predicted_gain(executed),
-                            "plan": plan, "job_id": jid,
-                            "state": stopped}
-            act = [n for n, j in self.jobs.items() if j.num_slices > 0]
-            cs = sched.find_coschedule(act)
-            if cs.k2 is None:
-                n_run = min(self.jobs[cs.k1].num_slices, 8)
-                for _ in range(n_run):
-                    self.outputs[cs.k1] = self._run(cs.k1)
-                    self.outputs[cs.k1].block_until_ready()
-                self.jobs[cs.k1].num_slices -= n_run
-                executed.append((cs.k1, None, n_run, 0, 0.0))
-                continue
-            # balanced interleave: issue s1:s2 slices per round, async
-            r1 = max(1, round(cs.s1 / self.spec.n_sm))
-            r2 = max(1, round(cs.s2 / self.spec.n_sm))
-            j1, j2 = self.jobs[cs.k1], self.jobs[cs.k2]
-            outs = []
-            n1 = min(r1, j1.num_slices)
-            n2 = min(r2, j2.num_slices)
-            for i in range(max(n1, n2)):
-                if i < n1:
-                    outs.append((cs.k1, self._run(cs.k1)))
-                if i < n2:
-                    outs.append((cs.k2, self._run(cs.k2)))
-            for name, o in outs:
-                self.outputs[name] = o.block_until_ready()
-            j1.num_slices -= n1
-            j2.num_slices -= n2
-            executed.append((cs.k1, cs.k2, n1, n2, cs.cp))
-            if len(executed) > max_rounds:
-                raise RuntimeError("scheduler did not drain")
-        wall = time.time() - t0
-        out = {"rounds": executed, "wall_s": wall,
-               "predicted_gain": self._predicted_gain(executed),
-               "plan": plan}
+            with _span("round", index=len(executed)):
+                if daemon is not None:
+                    with _span("control"):
+                        stopped = self._drain_control(daemon, jid, fence,
+                                                      len(executed))
+                    if stopped is not None:
+                        return result(job_id=jid, state=stopped)
+                act = [n for n, j in self.jobs.items() if j.num_slices > 0]
+                with _span("decide"):
+                    cs = sched.find_coschedule(act)
+                if cs.k2 is None:
+                    n_run = min(self.jobs[cs.k1].num_slices, 8)
+                    for _ in range(n_run):
+                        with _span("dispatch"):
+                            out, last = self._dispatch(cs.k1)
+                        with _span("block"):
+                            self.outputs[cs.k1] = out.block_until_ready()
+                        self._complete([last], completed)
+                    self.jobs[cs.k1].num_slices -= n_run
+                    executed.append((cs.k1, None, n_run, 0, 0.0))
+                    continue
+                # balanced interleave: enqueue s1:s2 slices per round, async
+                r1 = max(1, round(cs.s1 / self.spec.n_sm))
+                r2 = max(1, round(cs.s2 / self.spec.n_sm))
+                j1, j2 = self.jobs[cs.k1], self.jobs[cs.k2]
+                outs, lasts = [], []
+                n1 = min(r1, j1.num_slices)
+                n2 = min(r2, j2.num_slices)
+                with _span("dispatch"):
+                    for i in range(max(n1, n2)):
+                        for name, n in ((cs.k1, n1), (cs.k2, n2)):
+                            if i < n:
+                                out, last = self._dispatch(name)
+                                outs.append((name, out))
+                                lasts.append(last)
+                with _span("block"):
+                    for name, o in outs:
+                        self.outputs[name] = o.block_until_ready()
+                self._complete(lasts, completed)
+                j1.num_slices -= n1
+                j2.num_slices -= n2
+                executed.append((cs.k1, cs.k2, n1, n2, cs.cp))
+                if len(executed) > max_rounds:
+                    raise RuntimeError("scheduler did not drain")
+        out = result()
         if daemon is not None:
             out["job_id"] = jid
             try:
                 daemon.store.transition(
                     jid, FINISHED, "drained",
-                    result={"rounds": len(executed), "wall_s": wall,
+                    result={"rounds": len(executed), "wall_s": out["wall_s"],
                             "predicted_gain": out["predicted_gain"]},
                     fence=fence)
                 out["state"] = FINISHED
             except StaleLease:
                 out["state"] = "lost"
+            out["returned_at"] = time.perf_counter()
         return out
+
+    def _dispatch(self, name: str):
+        """Enqueue one slice of tenant ``name`` for the oldest of its
+        jobs, under the span ``kernelet.slice``. Returns the output and
+        that job's record if this was its last slice, else None."""
+        queue = self._queue.get(name)
+        job = queue[0] if queue else None
+        ids = {} if job is None else {"job_id": job.job_id}
+        with _span("slice", tenant=name, **ids):
+            if job is not None and job.first_dispatch is None:
+                job.first_dispatch = time.perf_counter()
+            out = self._run(name)
+        if job is None:
+            return out, None
+        job.left -= 1
+        if job.left:
+            return out, None
+        return out, queue.popleft()
+
+    @staticmethod
+    def _complete(lasts, completed: list):
+        """The wait covering these slices returned: their jobs are done."""
+        now = time.perf_counter()
+        for job in lasts:
+            if job is not None:
+                job.done = now
+                completed.append(job)
 
     def _run(self, name: str):
         return self._exec[name](*self._args[name])
@@ -449,9 +571,9 @@ def demo(device_kind: Optional[str] = None):
     server.submit(Job("tenantB-dsv2-decode", "deepseek-v2-236b", "decode", 24))
     server.submit(Job("tenantC-rwkv-prefill", "rwkv6-1.6b", "prefill", 16))
     server.submit(Job("tenantD-sc2-decode", "starcoder2-15b", "decode", 16))
-    for ev in server.log:
-        print("submitted", ev[1],
-              f"PUR={ev[2]:.2f} MUR={ev[3]:.2f} R_m={ev[4]:.2f}")
+    for name, prof in server.profiles.items():
+        print("submitted", name,
+              f"PUR={prof.pur:.2f} MUR={prof.mur:.2f} R_m={prof.rm:.2f}")
     res = server.drain()
     if res["plan"]:
         print(f"engine plan: predicted makespan "
