@@ -8,8 +8,11 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
+from jax.experimental.layout import Layout
 
 from repro.kernels import coschedule as _cs
+from repro.kernels import decode_attention as _da
 from repro.kernels import flash_attention as _fa
 from repro.kernels import rg_lru as _lru
 from repro.kernels import rwkv6_scan as _wkv
@@ -41,6 +44,27 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
                     bk: int = 128):
     return _fa.flash_attention(q, k, v, causal=causal, bq=bq, bk=bk,
                                interpret=_default_interpret())
+
+
+@functools.lru_cache(maxsize=None)
+def cache_positions_minor(shape: tuple, dtype, device=None) -> bool:
+    """Whether ``device`` (the default one if None) stores a ``(L, B, S,
+    KV, hd)`` cache with its positions (axis 2) minor-most, as a TPU does
+    where the head size is not a multiple of 128 lanes."""
+    device = device or jax.devices()[0]
+    layout = Layout.from_pjrt_layout(device.client.get_default_layout(
+        jnp.dtype(dtype), shape, device))
+    return layout.major_to_minor[-1] == 2
+
+
+def decode_attention(q, k_cache, v_cache, layer, t, k_new, v_new):
+    """One token's attention over layer ``layer`` of a stacked cache, read
+    in the orientation the device stores it in (see
+    ``kernels/decode_attention.py``)."""
+    return _da.decode_attention(
+        q, k_cache, v_cache, layer, t, k_new, v_new,
+        positions_minor=cache_positions_minor(k_cache.shape, k_cache.dtype),
+        interpret=_default_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

@@ -9,7 +9,9 @@ Two execution paths:
 Decode uses a single-token einsum over the cache; the cache is laid out
 (B, S, kv, hd) so GSPMD can shard B over 'data' and S over 'model'
 (context-parallel decode — partial softmax stats are combined by XLA's
-all-reduce on the contraction).
+all-reduce on the contraction). On one device, a full-attention GQA
+cache is instead read in place by the ``decode_attention`` kernel, from
+the stage's stacked cache (``reads_cache_in_place``).
 """
 from __future__ import annotations
 
@@ -18,8 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops
 from repro.models import layers as L
-from repro.models.sharding import constrain
+from repro.models.sharding import constrain, current_mesh
 
 NEG_INF = -1e30
 
@@ -203,15 +206,28 @@ def decode_attention(q, k_cache, v_cache, t, *, window: int = 0):
     return o.reshape(b, 1, h, hd).astype(q.dtype)
 
 
+def reads_cache_in_place(cfg, kind: str, seq_len: int) -> bool:
+    """Whether a ``kind`` block decoding ``seq_len`` tokens against its
+    cache attends with the ``decode_attention`` kernel: one token, a
+    full-attention GQA self-attention cache, one device (no mesh, whose
+    sharded lowerings keep the einsum path)."""
+    return (kind == "attn" and seq_len == 1 and cfg.mla is None
+            and cfg.attention_kind == "full" and current_mesh() is None)
+
+
 # --------------------------------------------------------------------- #
 # GQA block (projection + attention + output)
 # --------------------------------------------------------------------- #
 def gqa_forward(x, p, cfg, positions, *, causal=True, cache=None, t=None,
-                kv_source=None):
+                kv_source=None, layer=None):
     """x:(B,S,D). cache: dict(k,v) (B,Smax,kv,hd) or None.
 
     kv_source: if given (B,Skv,D), cross-attention (whisper decoder);
     positions apply to q only then.
+    layer: given for a one-token decode whose cache is the stage's whole
+    (L,B,Smax,kv,hd) cache (``reads_cache_in_place``); the kernel reads
+    layer ``layer`` of it, and the new cache is the token's own (B,1,kv,hd)
+    k and v, for the caller to write at ``t``.
     Returns (out, new_cache).
     """
     b, s, d = x.shape
@@ -233,7 +249,13 @@ def gqa_forward(x, p, cfg, positions, *, causal=True, cache=None, t=None,
     if cache is not None:
         if t is None:
             raise ValueError("cache update requires t")
-        if s == 1:  # decode: write one token at position t
+        if layer is not None:  # decode, reading the stacked cache in place
+            k_t = k.astype(cache["k"].dtype)
+            v_t = v.astype(cache["v"].dtype)
+            new_cache = {"k": k_t, "v": v_t}
+            o = ops.decode_attention(q[:, 0], cache["k"], cache["v"], layer,
+                                     t, k_t[:, 0], v_t[:, 0])[:, None]
+        elif s == 1:  # decode: write one token at position t
             k_c = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), t, 1)
             v_c = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), t, 1)
             new_cache = {"k": k_c, "v": v_c}

@@ -180,8 +180,11 @@ def _local_attention_block(x, p, cfg, positions, cache, t):
 
 
 def apply_block(x, bp, cfg, sig, positions, *, enc_out=None, cache=None,
-                t=None, moe_group: int = 0):
-    """One transformer block. Returns (x, new_cache, aux_loss)."""
+                t=None, moe_group: int = 0, layer=None):
+    """One transformer block. Returns (x, new_cache, aux_loss).
+
+    ``layer``: the block's index in its stage, given when ``cache`` holds
+    the stage's whole k and v (``A.reads_cache_in_place``)."""
     kind, is_moe = sig
     aux = jnp.zeros((), jnp.float32)
     h = L.norm(x, bp["norm1"], cfg.norm)
@@ -196,7 +199,7 @@ def apply_block(x, bp, cfg, sig, positions, *, enc_out=None, cache=None,
                                   cache=sub_cache or None, t=t)
         else:
             a, nc = A.gqa_forward(h, bp["attn"], cfg, positions,
-                                  cache=sub_cache or None, t=t)
+                                  cache=sub_cache or None, t=t, layer=layer)
         if nc is not None:
             new_cache.update(nc)
     elif kind == "local":
@@ -333,25 +336,49 @@ def _run_stages(params, cfg, x, positions, stages, *, prefix="stage",
     for si, st in enumerate(stages):
         sp = root[f"{prefix}{si}"] if prefix == "stage" else root[prefix][f"stage{si}"]
         cache_s = caches.get(f"{prefix}{si}") if caches is not None else None
+        # sublayers whose attention reads the stage's whole k and v in
+        # place: those stay out of the scan's per-layer slices, and the
+        # scan returns only each layer's new token, written after it
+        whole = set()
+        if decode and cache_s is not None:
+            whole = {f"sub{ci}" for ci, (kind, _) in enumerate(st.cycle)
+                     if A.reads_cache_in_place(cfg, kind, x.shape[1])}
+        xs_cache = cache_s
+        if whole:
+            xs_cache = {n: {k: a for k, a in c.items()
+                            if n not in whole or k not in ("k", "v")}
+                        for n, c in cache_s.items()}
 
-        def body(carry, xs, _st=st):
+        def body(carry, xs, _st=st, _whole=whole, _cache_s=cache_s):
             xx = carry
-            layer_ps, layer_cs = xs
+            layer_ps, layer_cs, i = xs
             aux_acc = jnp.zeros((), jnp.float32)
             ncs = {}
             for ci, sig in enumerate(_st.cycle):
-                cc = layer_cs.get(f"sub{ci}") if layer_cs is not None else None
+                name = f"sub{ci}"
+                cc = layer_cs.get(name) if layer_cs is not None else None
+                layer = None
+                if name in _whole:
+                    cc = dict(cc, k=_cache_s[name]["k"], v=_cache_s[name]["v"])
+                    layer = i
                 xx, nc, aux = apply_block(
-                    xx, layer_ps[f"sub{ci}"], cfg, sig, positions,
-                    enc_out=enc_out, cache=cc, t=t, moe_group=moe_group)
+                    xx, layer_ps[name], cfg, sig, positions,
+                    enc_out=enc_out, cache=cc, t=t, moe_group=moe_group,
+                    layer=layer)
                 if new_caches is not None:
-                    ncs[f"sub{ci}"] = nc
+                    ncs[name] = nc
                 aux_acc = aux_acc + aux
             return xx, (ncs if new_caches is not None else 0, aux_acc)
 
         if cfg.remat and not decode:
             body = jax.checkpoint(body)
-        x, (ncs, auxs) = jax.lax.scan(body, x, (sp, cache_s))
+        layers = jnp.arange(st.repeats) if whole else None
+        x, (ncs, auxs) = jax.lax.scan(body, x, (sp, xs_cache, layers))
+        for name in whole:                 # (L, B, 1, kv, hd) at position t
+            ncs[name] = dict(ncs[name], **{
+                k: jax.lax.dynamic_update_slice_in_dim(
+                    cache_s[name][k], ncs[name][k], t, 2)
+                for k in ("k", "v")})
         if new_caches is not None:
             new_caches[f"{prefix}{si}"] = ncs
         aux_total = aux_total + jnp.sum(auxs)

@@ -8,6 +8,7 @@ pass. Kernels are called with ``interpret=False`` directly, because the
 ``ops`` wrappers see the CPU backend and would interpret.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.core.profiles import V5E, device_peaks
 from repro.kernels import coschedule as cs
+from repro.kernels import decode_attention as da
 from repro.kernels import flash_attention as fa
 from repro.kernels import rg_lru as lru
 from repro.kernels import rwkv6_scan as wkv
@@ -71,11 +73,24 @@ def _kernel_case(name):
     if name == "rg_lru":
         return (lambda x, a: lru.rg_lru(x, a),
                 [((1, 2048, 4096), f32)] * 2)
+    if name.startswith("decode_attention"):
+        # phi3's cache (head 96: stored positions minor), and a GQA one
+        # (starcoder2-15b: 48 heads on 4 kv heads of 128, row-major)
+        layers, h, kvh, hd, minor = ((32, 32, 32, 96, True)
+                                     if name == "decode_attention"
+                                     else (40, 48, 4, 128, False))
+        cache = ((layers, 16, 512, kvh, hd), bf16)
+        return (lambda q, kc, vc, layer, t, kn, vn: da.decode_attention(
+                    q, kc, vc, layer, t, kn, vn, positions_minor=minor),
+                [((16, h, hd), bf16), cache, cache, ((), jnp.int32),
+                 ((), jnp.int32), ((16, kvh, hd), bf16),
+                 ((16, kvh, hd), bf16)])
     raise KeyError(name)
 
 
 @pytest.mark.parametrize("name", ["coschedule", "sliced_matmul",
-                                  "flash_attention", "rwkv6_scan", "rg_lru"])
+                                  "flash_attention", "rwkv6_scan", "rg_lru",
+                                  "decode_attention", "decode_attention_gqa"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _kernel_case(name)
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
@@ -105,3 +120,53 @@ def test_phi3_decode_step_compiles_for_v5e(one_chip):
     assert mem.argument_size_in_bytes >= weights      # weights are arguments
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < device_peaks(V5E).hbm_bytes
+
+
+def test_phi3_decode_reads_cache_in_place_on_v5e(one_chip, monkeypatch):
+    """The served decode step at the cell's shapes (batch 16, cache 512):
+    the ``decode_attention`` kernel reads each layer's k and v from the
+    stacked cache where it lies, so no slice, copy, relayout or write of a
+    layer's cache or of the stacked cache is left, and the temp stays in
+    the few-MB range."""
+    from repro.kernels import ops
+
+    # the model path asks the default device (here the CPU) whether to
+    # interpret and how the cache is laid out: answer for the chip
+    chip = next(iter(one_chip.device_set))
+    positions_minor = ops.cache_positions_minor
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    monkeypatch.setattr(ops, "cache_positions_minor",
+                        lambda shape, dtype: positions_minor(shape, dtype,
+                                                             chip))
+    cfg = get_config("phi3-mini-3.8b")
+    batch, max_len = 16, 512
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(T.init_params, cfg),
+                                    jax.random.PRNGKey(0)))
+    caches = on_chip(jax.eval_shape(functools.partial(
+        T.init_decode_caches, cfg, batch, max_len)))
+    tok = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    step = functools.partial(serve.decode_logits, cfg=cfg)
+    compiled = jax.jit(step).lower(params, caches, tok, t).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the chip stores the cache with positions minor, as the kernel reads it
+    for fmt in compiled.input_formats[0][1]["stage0"]["sub0"].values():
+        assert fmt.layout.major_to_minor[-1] == 2, fmt
+    n_l, kvh, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+    cache_shapes = {f"bf16[{','.join(map(str, s))}]" for s in (
+        (1, batch, max_len, kvh, hd), (batch, max_len, kvh, hd),
+        (n_l, batch, max_len, kvh, hd))}
+    made = re.findall(r"^\s*(?:ROOT )?%\S+ = (bf16\[[\d,]*\])\S* ([\w-]+)\(",
+                      text, re.MULTILINE)
+    # the two cache parameters, and the layer loop's handles on them
+    ops_on_cache = [op for shape, op in made if shape in cache_shapes]
+    assert ops_on_cache.count("parameter") == 2
+    assert set(ops_on_cache) == {"parameter", "get-tuple-element"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
