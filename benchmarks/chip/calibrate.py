@@ -9,7 +9,8 @@ In one process (set-up is long): a short run of the cell, exactly as
 first ``--control-seeds`` seeds the control's readings: the reference put
 in the program's place in a lower precision (fp8 e4m3, and int8 for
 comparison) on the same weights and every input set of each tenant's
-pool, read against the float32 reference. ``--published`` also reads the
+pool, read against the float32 reference (both the configuration's
+``equations.Reference``). ``--published`` also reads the
 reference with the configuration's keys set to their published values
 against the reference as the file states it: the size, in the same
 units, of what the program departs from. Prints one JSON line. Not part
@@ -24,13 +25,12 @@ import run
 
 def control_readings(jax, spec: dict, seed: int, published: dict) -> dict:
     import correct
-    import reference
     s = run.Session(jax, spec["config"], spec["traffic"], seed,
                     on_chip=True)
     s.srv = None
     gc.collect()
-    ref = reference.Dense(spec["config"])
-    pub = reference.Dense({**spec["config"], **published}) if published \
+    ref = s.eq.Reference(spec["config"])
+    pub = s.eq.Reference({**spec["config"], **published}) if published \
         else None
     out = {}
     for name, t in s.tenants.items():

@@ -1,6 +1,6 @@
 """Step programs against the roofline: the least time the decode step's
-required work allows at the chip's peaks (``work.py``, ``peaks.py``) over
-its measured mean device time per call."""
+required work allows at the chip's peaks (``equations.step``,
+``peaks.py``) over its measured mean device time per call."""
 
 
 def read(rec):

@@ -1,7 +1,7 @@
 """Device: FLOPs that the step programs run in the traced window require
-(``work.py``), over the window times the chip's bf16 peak. The whole
-step's share of peak, which still bounds a gain after a kernel leaves the
-path."""
+(the configuration's ``equations.step``), over the window times the
+chip's bf16 peak. The whole step's share of peak, which still bounds a
+gain after a kernel leaves the path."""
 
 
 def read(rec):

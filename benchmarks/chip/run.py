@@ -12,9 +12,11 @@ plain float32 reference; print one JSON line.
 
 Everything about a cell is data found by name: ``BENCHMARK.json`` at the
 checkout's root names the cell's configuration (``configs/<name>.json``)
-and traffic mix (``traffic/<name>.json``); the mix names its loop kind
-(``loops/<kind>.py``); each per-layer metric is read by
-``metrics/<name>.py``.
+and traffic mix (``traffic/<name>.json``); the configuration names its
+equations (``equations/<name>.py``: the program check, the plain
+reference, the work of a step and of each kernel); the mix names its loop
+kind (``loops/<kind>.py``); each per-layer metric is read by
+``metrics/<name>.py``. A new architecture is new files and entries.
 
 With ``--trace 0`` the metrics are the cell's end-to-end metrics; with
 ``--trace 1`` they are its per-layer metrics, read from a profiler trace
@@ -69,8 +71,23 @@ def load_plugin(kind: str, name: str):
     spec = importlib.util.spec_from_file_location(
         f"chipbench_{kind}_{name.replace('-', '_').replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod        # dataclasses look their module up
     spec.loader.exec_module(mod)
     return mod
+
+
+def equations(config: dict):
+    """The equations module the configuration file names under
+    ``program.equations``. There is no default: a dense count or
+    reference silently applied to another architecture would read work
+    that no program does."""
+    name = config.get("program", {}).get("equations")
+    if not name:
+        raise RuntimeError(
+            f"configuration {config.get('name')!r} names no equations "
+            f"module: set \"program\": {{\"equations\": <name>}} to a file "
+            f"equations/<name>.py under {HERE}")
+    return load_plugin("equations", name)
 
 
 def resolve(workload: str) -> dict:
@@ -145,11 +162,12 @@ class Session:
         from repro.launch.serve import Job, SharedPodServer
         from repro.models import transformer as T
 
+        self.eq = equations(config)
         prog = config["program"]
         cfg = get_config(prog["arch"])
         if not prog["published"]:
             cfg = reduced(cfg)
-        check_program_config(cfg, config)
+        self.eq.check(cfg, config)
         self.config = config
         self.tenants = {t["name"]: t for t in traffic["tenants"]}
         self.srv = SharedPodServer(
@@ -178,9 +196,12 @@ class Session:
             f"{t2 - t1:.3f}s (compile " + json.dumps(
                 {n: round(c, 3) for n, c in self.srv.compile_s.items()})
             + f"), warm-up drain {time.perf_counter() - t2:.3f}s")
-        self.work = {n: work_mod.step(config, t["phase"], t["batch"],
-                                      t["seq"])
+        self.work = {n: self.eq.step(config, t["phase"], t["batch"],
+                                     t["seq"])
                      for n, t in self.tenants.items()}
+        self.kernels = {n: self.eq.kernels(config, t["phase"], t["batch"],
+                                           t["seq"])
+                        for n, t in self.tenants.items()}
 
     def _own_inputs(self, jax, weights, name, tenant, seed, stream):
         """Replace the inputs ``submit`` made (the same for every seed) by
@@ -214,28 +235,6 @@ class Session:
             self.inputs[name] = [{"caches": caches, "tokens": x, "t": int(t)}
                                  for x in toks]
         self.srv._args[name] = self.pools[name][0]
-
-
-def check_program_config(cfg, config: dict):
-    """The program must run the configuration the file states."""
-    want = {"num_layers": config["num_hidden_layers"],
-            "d_model": config["hidden_size"],
-            "num_heads": config["num_attention_heads"],
-            "num_kv_heads": config["num_key_value_heads"],
-            "head_dim": config["hidden_size"] // config["num_attention_heads"],
-            "d_ff": config["intermediate_size"],
-            "vocab_size": config["vocab_size"],
-            "rope_theta": float(config["rope_theta"]),
-            "norm": "layernorm" if "layer_norm_eps" in config else "rmsnorm",
-            "act": "swiglu", "dtype": config["torch_dtype"],
-            "tie_embeddings": config["tie_word_embeddings"],
-            "pos_kind": "rope", "block_pattern": ("attn",),
-            "attention_kind": "full", "moe": None, "mla": None}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        diff = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
-        raise RuntimeError(f"the program's config departs from the file: "
-                           f"{diff} (program, file)")
 
 
 # --------------------------------------------------------------------- #
@@ -420,7 +419,8 @@ def end_to_end(win: Window, setup_s: float) -> dict:
 
 class Record:
     """What a per-layer metric reads: the window's drains, the reduced
-    trace, the required work of each tenant's step and the chip's peaks."""
+    trace, the required work of each tenant's step and of its kernels, and
+    the chip's peaks."""
 
     def __init__(self, win: Window, trace: dict, peaks):
         self.drains = win.drains
@@ -428,6 +428,7 @@ class Record:
         self.peaks = peaks
         self.tenants = win.s.tenants
         self.work = win.s.work
+        self.kernels = win.s.kernels
         self.per_phase = {
             t["phase"]: trace["modules"][t["phase"]]
             for t in self.tenants.values()
@@ -438,19 +439,43 @@ class Record:
         p = self.per_phase.get(phase)
         return p["device_s"] / p["count"] if p and p["count"] else None
 
+    def _same(self, by_tenant: dict, phase: str):
+        """The value of ``by_tenant`` that every tenant of ``phase``
+        shares, or None."""
+        vals = [by_tenant[n] for n, t in self.tenants.items()
+                if t["phase"] == phase]
+        if not vals or any(v != vals[0] for v in vals):
+            return None
+        return vals[0]
+
     def step_work(self, phase: str):
         """Required work of one step of ``phase``, if every tenant of that
         phase runs the same step."""
-        works = [self.work[n] for n, t in self.tenants.items()
-                 if t["phase"] == phase]
-        if not works or any(w != works[0] for w in works):
-            return None
-        return works[0]
+        return self._same(self.work, phase)
 
     def least_s(self, phase: str):
         """Least time of one step of ``phase`` at the table's peaks."""
         w = self.step_work(phase)
         return None if w is None else work_mod.least_time(w, self.peaks)
+
+    def kernel_s(self, phase: str, stem: str):
+        """Mean device time, per step of ``phase``, of the runs of the
+        kernel whose trace name has the stem ``stem``; None where it never
+        ran in the traced window."""
+        p = self.per_phase.get(phase)
+        k = (self.trace or {}).get("kernels", {}).get(phase, {}).get(stem)
+        if not p or not p["count"] or not k:
+            return None
+        return k["device_s"] / p["count"]
+
+    def kernel_least_s(self, phase: str, stem: str):
+        """Least time of that kernel's required work per step of
+        ``phase`` (the configuration's ``equations.kernels``) at the
+        table's peaks; None where the equations define none."""
+        ks = self._same(self.kernels, phase)
+        if not ks or stem not in ks:
+            return None
+        return work_mod.least_time(ks[stem], self.peaks)
 
 
 def read_trace(trace_dir: str, dispatched: list) -> dict:
@@ -478,8 +503,7 @@ def correctness(jax, win: Window, session: Session, config: dict) -> dict:
     from, beside its limit; and the slices each tenant was admitted but
     its step never ran (limit 0)."""
     import correct
-    import reference
-    ref = reference.Dense(config)
+    ref = session.eq.Reference(config)
     checks = {}
     for name, t in session.tenants.items():
         by_pool = {}

@@ -57,6 +57,14 @@ def test_config_file(conf):
     assert any(w["config"] == conf["name"] for w in CELLS.values())
 
 
+@pytest.mark.parametrize("conf", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_names_its_equations(conf):
+    body = json.loads((ROOT / conf["file"]).read_text())
+    name = body["program"]["equations"]
+    assert NAME.match(name)
+    assert (CHIP / "equations" / f"{name}.py").is_file()
+
+
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda c: c["name"])
 def test_cell_finds_its_files(cell):
     assert set(cell) == {"name", "config", "traffic", "chips", "why"}

@@ -115,7 +115,8 @@ def test_small_trace_reduces_as_before():
     assert raw["program"] == []
     old = tr.reduce(raw)
     assert set(old) == {"window_s", "busy_s", "devices", "modules",
-                        "device_ops", "idle_gaps", "idle_by_span"}
+                        "kernels", "device_ops", "idle_gaps",
+                        "idle_by_span"}
     assert old["idle_by_span"]["wait_arrival"] > 0.15
     assert old["modules"]["jit_mm"]["count"] == record_trace.MM_RUNS
     new = pt.reduce(raw)
@@ -159,7 +160,7 @@ TRACE = {"window_s": 10.0, "busy_s": 9.7, "devices": 1,
 
 
 def record(drains=DRAINS, trace=TRACE):
-    s = SimpleNamespace(tenants={}, work={})
+    s = SimpleNamespace(tenants={}, work={}, kernels={})
     return run.Record(SimpleNamespace(drains=drains, s=s), trace, None)
 
 
@@ -175,6 +176,32 @@ def read(name, rec):
     ("idle_program_share", 2.0)])
 def test_reader(name, value):
     assert read(name, record()) == pytest.approx(value)
+
+
+def test_decode_attention_roofline_reads_nothing_on_a_trace_without_it(
+        raw):
+    """The program trace's decode steps ran before the decode kernel
+    existed: their step time reads, the kernel's roofline does not."""
+    dense = run.load_plugin("equations", "dense")
+    conf = json.loads((pathlib.Path(__file__).resolve().parent / "data"
+                       / "tiny-phi3.json").read_text())
+    tenants = {"prefill": {"phase": "prefill", "batch": 2, "seq": 64},
+               "decode": {"phase": "decode", "batch": 4, "seq": 64}}
+    phases = [n[len("jit_"):-len("_step")] for n, _ in tr.module_runs(raw)]
+    trace = tr.reduce(raw, run_labels=phases)
+    s = SimpleNamespace(
+        tenants=tenants,
+        work={n: dense.step(conf, t["phase"], t["batch"], t["seq"])
+              for n, t in tenants.items()},
+        kernels={n: dense.kernels(conf, t["phase"], t["batch"], t["seq"])
+                 for n, t in tenants.items()})
+    import peaks
+    rec = run.Record(SimpleNamespace(drains=DRAINS, s=s), trace,
+                     peaks.peaks_for("TPU v5 lite"))
+    assert rec.kernel_least_s("decode", "decode_attention") > 0
+    assert "decode_attention" not in trace["kernels"]["decode"]
+    assert read("decode_step_ms", rec) > 0
+    assert read("decode_attention_roofline", rec) is None
 
 
 @pytest.mark.parametrize("m", pt.PROGRAM_METRICS, ids=lambda m: m["name"])

@@ -1,6 +1,8 @@
-"""The plain reference against the program's own forward and decode step
-at a CPU test size, both in float32 (so only the equations can differ),
-and the lower-precision control against the limit."""
+"""The plain reference (the dense equations module's) against the
+program's own forward and decode step at a CPU test size, both in float32
+(so only the equations can differ), against its own logits from before it
+moved into ``equations/dense.py``, and the lower-precision control against
+the limit."""
 import json
 import pathlib
 
@@ -10,11 +12,12 @@ import numpy as np
 import pytest
 
 import correct
-import reference
+import run
 import weights
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 CONFIGS = ["tiny-phi3", "tiny-stablelm"]
+dense = run.load_plugin("equations", "dense")
 
 
 def setup(name, seed, dtype):
@@ -37,7 +40,7 @@ def test_prefill_matches_program_in_f32(name):
                               weights.base_key(3, 1))
     with jax.default_matmul_precision("highest"):
         prog = T.forward(params, cfg, {"tokens": tok})[0]
-    ref = reference.Dense(conf).prefill_logits(params, tok)
+    ref = dense.Reference(conf).prefill_logits(params, tok)
     np.testing.assert_allclose(np.asarray(prog), np.asarray(ref),
                                atol=2e-5, rtol=1e-4)
 
@@ -53,7 +56,7 @@ def test_decode_matches_program_in_f32(name):
                               weights.base_key(4, 1))
     with jax.default_matmul_precision("highest"):
         prog = T.decode_step(params, cfg, caches, tok, jnp.int32(16))[0]
-    ref = reference.Dense(conf).decode_logits(params, caches, tok, 16)
+    ref = dense.Reference(conf).decode_logits(params, caches, tok, 16)
     np.testing.assert_allclose(np.asarray(prog), np.asarray(ref),
                                atol=2e-5, rtol=1e-4)
 
@@ -68,7 +71,7 @@ def test_lower_precision_fails_the_comparison(name):
     from repro.launch.serve import prefill_logits
     conf = json.loads((DATA / f"{name}.json").read_text())
     limit = conf["limits"]["prefill_max_logit_gap"]
-    ref = reference.Dense(conf)
+    ref = dense.Reference(conf)
     for seed in (11, 12, 13):
         _, cfg, params = setup(name, seed, jnp.bfloat16)
         tok = weights.make_tokens((2, 64), conf["vocab_size"],
@@ -79,6 +82,38 @@ def test_lower_precision_fails_the_comparison(name):
         prog = jax.jit(functools.partial(prefill_logits, cfg=cfg))(
             params, {"tokens": tok})
         assert correct.widest_gap(prog, want) <= limit
+
+
+BEFORE = json.loads((DATA / "dense-reference-logits.json").read_text())
+
+
+@pytest.mark.parametrize("quant", [None, "fp8", "int8"])
+@pytest.mark.parametrize("name", CONFIGS)
+def test_logits_unchanged_by_the_move(name, quant):
+    """The module's logits are the ones ``reference.Dense`` gave on the
+    same seeded weights. Decode is compared bit for bit. Prefill's larger
+    matmuls are split over XLA's CPU threads, whose count moves a float32
+    sum by a few ulps (<= 2e-6 seen between one thread and eight), so it
+    is held to 1e-5; a change to an equation moves it by far more."""
+    from repro.models import transformer as T
+    conf, cfg, params = setup(name, 7, jnp.float32)
+    tok = weights.make_tokens((2, 32), conf["vocab_size"],
+                              weights.base_key(7, 1))
+    shapes = jax.eval_shape(
+        lambda: T.init_decode_caches(cfg, 3, 32, jnp.float32))
+    caches = weights.make_caches(shapes, weights.base_key(7, 2))
+    dtok = weights.make_tokens((3,), conf["vocab_size"],
+                               weights.base_key(7, 3))
+    ref = dense.Reference(conf)
+    key = f"{name}.{quant or 'f32'}"
+    pre = np.asarray(ref.prefill_logits(params, tok, quant=quant))
+    dec = np.asarray(ref.decode_logits(params, caches, dtok, 16,
+                                       quant=quant))
+    np.testing.assert_allclose(
+        pre[:, ::8, :8].ravel(),
+        np.float32(BEFORE["logits"][f"{key}.prefill"]), rtol=0, atol=1e-5)
+    np.testing.assert_array_equal(
+        dec[:, :8].ravel(), np.float32(BEFORE["logits"][f"{key}.decode"]))
 
 
 def test_gap_reads_the_served_tokens():

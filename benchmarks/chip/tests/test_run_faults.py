@@ -41,11 +41,12 @@ def half_batch_left_out(s):
 
 
 def control_in_place(s):
-    """The reference in fp8 put in the prefill tenant's place."""
-    import reference
-    ref = reference.Dense(s.config)
-    s.srv._exec["prefill"] = lambda params, batch: ref.prefill_logits(
-        params, batch["tokens"], quant="fp8")
+    """The reference in fp8 put in every prefill tenant's place."""
+    ref = s.eq.Reference(s.config)
+    for name, t in s.tenants.items():
+        if t["phase"] == "prefill":
+            s.srv._exec[name] = lambda params, batch: ref.prefill_logits(
+                params, batch["tokens"], quant="fp8")
 
 
 def output_reused(s):
@@ -106,6 +107,25 @@ def test_sound_run_is_correct(config):
     assert list(out)[-1] == "checks"
 
 
+def prefill_closed():
+    """The prefill-closed mix at a CPU test size."""
+    mix = json.loads((run.HERE / "traffic" / "prefill-closed.json")
+                     .read_text())
+    for t in mix["tenants"]:
+        t.update(seq=64, clients=2)
+    return {**spec(), "traffic": mix}
+
+
+def test_two_prefill_tenants_run_correct():
+    """Two prefill tenants share the weights and both are checked."""
+    out = run.run_cell(prefill_closed(), 2**31 + 10, 1.0, False,
+                       on_chip=False)
+    assert out["correct"], out["checks"]
+    assert set(out["checks"]) == {
+        f"{n}.{c}" for n in ("prefill-a", "prefill-b")
+        for c in ("max_logit_gap", "slices_not_run")}
+
+
 @pytest.mark.parametrize("fault", [token_altered, half_batch_left_out,
                                    control_in_place, output_reused,
                                    interleaved_slices_altered,
@@ -113,6 +133,16 @@ def test_sound_run_is_correct(config):
 def test_broken_timed_path_is_not_correct(fault):
     out = run.run_cell(spec(), 2**31 + 4, 1.0, False, on_chip=False,
                        fault=fault)
+    assert not out["correct"], out["checks"]
+
+
+@pytest.mark.parametrize("fault", [token_altered, half_batch_left_out,
+                                   control_in_place, output_reused,
+                                   interleaved_slices_altered,
+                                   slices_skipped])
+def test_broken_prefill_closed_is_not_correct(fault):
+    out = run.run_cell(prefill_closed(), 2**31 + 11, 1.0, False,
+                       on_chip=False, fault=fault)
     assert not out["correct"], out["checks"]
 
 

@@ -2,6 +2,7 @@
 ``record_trace.py``: inside a ``window`` span, 3 runs of ``jit_mm`` each
 under a ``drain`` span and followed by a 50 ms sleep under
 ``wait_arrival``, then 5 runs of ``jit_scale`` under ``admit``."""
+import json
 import pathlib
 
 import pytest
@@ -9,8 +10,10 @@ import pytest
 import record_trace
 import trace_reduce as tr
 
-TRACE = pathlib.Path(__file__).resolve().parents[1] / "testdata" / \
-    "small.xplane.pb"
+TESTDATA = pathlib.Path(__file__).resolve().parents[1] / "testdata"
+TRACE = TESTDATA / "small.xplane.pb"
+BEFORE = json.loads((pathlib.Path(__file__).resolve().parent / "data"
+                     / "reductions-before-kernels.json").read_text())
 
 
 @pytest.fixture(scope="module")
@@ -115,3 +118,45 @@ def test_interval_helpers():
     assert tr.module_name("jit_prefill_logits(123)") == "jit_prefill_logits"
     assert tr.op_label("%fusion.1 = bf16[2,3]{1,0:T(8,128)} fusion(%x)") == \
         "%fusion.1 bf16[2,3]"
+
+
+@pytest.mark.parametrize("name", sorted(BEFORE))
+def test_reduces_as_before_kernels_were_kept(name):
+    """``small.xplane.pb`` and ``program.xplane.pb`` reduce to the
+    programs, costliest ops and idle gaps they did before the reduction
+    also kept every kernel's time."""
+    red = tr.reduce(tr.read(str(TESTDATA / f"{name}.xplane.pb")))
+    assert red["modules"] == BEFORE[name]["modules"]
+    assert red["device_ops"] == BEFORE[name]["device_ops"]
+    assert red["idle_gaps"] == BEFORE[name]["idle_gaps"]
+
+
+@pytest.mark.parametrize("name", sorted(BEFORE))
+def test_kernel_totals_add_up_to_the_leaf_ops(name):
+    raw = tr.read(str(TESTDATA / f"{name}.xplane.pb"))
+    red = tr.reduce(raw)
+    win = [e for e in raw["host"] if e[0] == "window"][0]
+    ops = [(s, e) for d in raw["devices"].values()
+           for _n, s, e in tr.leaves(d["ops"]) if e > win[1] and s < win[2]]
+    kernels = [k for per in red["kernels"].values() for k in per.values()]
+    assert sum(k["count"] for k in kernels) == len(ops)
+    assert sum(k["device_s"] for k in kernels) == pytest.approx(
+        sum(e - s for s, e in ops) * 1e-9, rel=1e-12)
+    # every op of the costliest list is counted under its program and stem
+    for key, secs in red["device_ops"]:
+        owner, _, label = key.partition("/")
+        stem = tr.op_stem(label.split(" ")[0])
+        assert red["kernels"][owner][stem]["device_s"] >= \
+            secs * (1 - 1e-12)
+
+
+def test_kernels_are_named_by_stem(red):
+    assert red["kernels"]["jit_mm"]["fusion"]["count"] == \
+        record_trace.MM_RUNS
+    assert red["kernels"]["jit_scale"]["multiply_add_fusion"]["count"] == \
+        record_trace.SCALE_RUNS
+    assert tr.op_stem("%decode_attention.31 = bf16[16,32,96]{2,1,0} "
+                      "custom-call(%a)") == "decode_attention"
+    assert tr.op_stem("%bitcast_dynamic-update-slice_fusion.2") == \
+        "bitcast_dynamic-update-slice_fusion"
+    assert tr.op_stem("%copy-start") == "copy-start"

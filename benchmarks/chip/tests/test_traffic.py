@@ -87,3 +87,20 @@ def test_closed_loop_is_the_same_work_for_every_seed():
         load_loop("closed").drive(win, mix("pd-closed"), seed)
         logs.append(win.log)
     assert logs[0] == logs[1]
+
+
+def test_prefill_closed_is_two_of_pd_closed_prefill_tenants():
+    """The control mix: two prefill tenants, each the pd-closed prefill
+    tenant under its own name, so each draws its own order of the same
+    sizes."""
+    pd = {t["name"]: t for t in mix("pd-closed")["tenants"]}["prefill"]
+    pc = mix("prefill-closed")
+    assert [t["name"] for t in pc["tenants"]] == ["prefill-a", "prefill-b"]
+    for t in pc["tenants"]:
+        assert {k: v for k, v in t.items() if k != "name"} == \
+            {k: v for k, v in pd.items() if k != "name"}
+    a, b = (list(itertools.islice(
+        traffic.size_stream(t["slices"], "sizes/" + t["name"]), 32))
+        for t in pc["tenants"])
+    assert sorted(a) == sorted(b) and a != b
+    assert set(pc["stands_for"]) and pc["loop"] == "closed"

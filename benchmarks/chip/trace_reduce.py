@@ -1,6 +1,7 @@
 """Reduce a JAX profiler trace (``.xplane.pb``) to device busy and idle
-time, per-program device time, the costliest device ops, and the longest
-idle gaps labelled by the host span the harness had open at the time.
+time, per-program device time, every kernel's device time per program, the
+costliest device ops, and the longest idle gaps labelled by the host span
+the harness had open at the time.
 
 Reads the trace with ``jax.profiler.ProfileData`` alone. Device planes are
 ``/device:TPU:<n>``; their ``XLA Ops`` line holds one event per op run and
@@ -18,6 +19,7 @@ WINDOW_SPAN = "window"
 LABEL_SPANS = ("admit", "drain", "wait_arrival")
 DISPATCH = "PJRT_LoadedExecutable_Execute"   # host: one per program run
 _SUFFIX = re.compile(r"\(\d+\)$")
+_INSTANCE = re.compile(r"\.\d+$")
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -126,6 +128,12 @@ def op_label(name: str) -> str:
     return f"{lhs} {'tuple' if rtype.startswith('(') else rtype}".strip()
 
 
+def op_stem(name: str) -> str:
+    """``%decode_attention.3 = bf16[...] custom-call(...)`` ->
+    ``decode_attention``: the kernel, whichever instance of it ran."""
+    return _INSTANCE.sub("", name.partition(" = ")[0].strip().lstrip("%"))
+
+
 def leaves(ops) -> list:
     """The ops that contain no other op: a while or call op spans the ops
     of its body on the same line."""
@@ -145,8 +153,11 @@ def reduce(raw: dict, *, run_labels=None, top: int = 10) -> dict:
     """Busy, idle and per-program device time within the harness's
     ``window`` span (or, without one, the extent of the device events).
     ``run_labels``, one per program run of the first device in the order
-    they ran, names the programs in place of their module names. Times in
-    seconds; ``busy_s`` is averaged over the devices."""
+    they ran, names the programs in place of their module names.
+    ``kernels`` holds, per program and per op stem (``op_stem``), the
+    count and summed device time of every leaf op in the window, where
+    ``device_ops`` keeps the ``top`` costliest ops. Times in seconds;
+    ``busy_s`` is averaged over the devices."""
     win = [e for e in raw["host"] if e[0] == WINDOW_SPAN]
     devs = raw["devices"]
     if not devs or not any(d["ops"] for d in devs.values()):
@@ -159,7 +170,7 @@ def reduce(raw: dict, *, run_labels=None, top: int = 10) -> dict:
     spans = sorted((e for e in raw["host"] if e[0] in LABEL_SPANS),
                    key=lambda e: e[1])
     starts = [e[1] for e in spans]
-    busy_total, modules, op_time, idle = 0.0, {}, {}, []
+    busy_total, modules, op_time, kernels, idle = 0.0, {}, {}, {}, []
     for k, dev in enumerate(sorted(devs)):
         dev = devs[dev]
         busy = union(clip([(s, e) for _, s, e in dev["ops"]], lo, hi))
@@ -183,6 +194,10 @@ def reduce(raw: dict, *, run_labels=None, top: int = 10) -> dict:
             owner = mods[i][2] if i >= 0 and s < mods[i][1] else "?"
             key = f"{owner}/{op_label(name)}"
             op_time[key] = op_time.get(key, 0.0) + (e - s) * 1e-9
+            k = kernels.setdefault(owner, {}).setdefault(
+                op_stem(name), {"count": 0, "device_s": 0.0})
+            k["count"] += 1
+            k["device_s"] += (e - s) * 1e-9
     n_dev = len(devs)
     idle_by_span = {}
     labelled = []
@@ -196,6 +211,7 @@ def reduce(raw: dict, *, run_labels=None, top: int = 10) -> dict:
         "busy_s": busy_total * 1e-9 / n_dev,
         "devices": n_dev,
         "modules": modules,
+        "kernels": kernels,
         "device_ops": [[k, v] for k, v in
                        sorted(op_time.items(), key=lambda x: -x[1])[:top]],
         "idle_gaps": [[k, v] for k, v in labelled[:top]],
