@@ -15,6 +15,7 @@ from repro.configs.base import (  # noqa: F401
     SMOKE_SHAPE,
     ShapeSpec,
     TRAIN_4K,
+    YarnConfig,
     applicable_shapes,
     reduced,
 )
@@ -29,6 +30,7 @@ _REGISTRY = {
     "whisper-small": "whisper_small",
     "recurrentgemma-9b": "recurrentgemma_9b",
     "deepseek-v2-236b": "deepseek_v2_236b",
+    "deepseek-v2-236b-ep8": "deepseek_v2_236b_ep8",
     "deepseek-v3-671b": "deepseek_v3_671b",
     "qwen2-vl-7b": "qwen2_vl_7b",
 }

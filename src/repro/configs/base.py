@@ -24,6 +24,35 @@ class MoEConfig:
     router_act: str = "softmax"      # softmax | sigmoid (DeepSeek-V3)
     a2a_dtype: str = "bf16"          # bf16 | int8 (quantized EP dispatch
                                      # with per-row scales; halves ICI bytes)
+    # group-limited routing (DeepSeek-V2's device-limited routing): experts
+    # in n_group equal groups, each token's top_k drawn from its
+    # topk_group groups of highest max score; 1 and 1 route over all
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True      # renormalise the top-k weights, else
+    routed_scaling_factor: float = 1.0   # scale them by this
+    # the experts this chip holds, as a (start, stop) range of expert ids
+    # (one routing group under expert parallelism); None holds all. A
+    # holding layer routes over all num_experts and computes only the
+    # held experts' part, with no capacity and no drop
+    held: Optional[tuple] = None
+
+    @property
+    def num_held(self) -> int:
+        return self.num_experts if self.held is None else \
+            self.held[1] - self.held[0]
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as Hugging Face's
+    ``DeepseekV2YarnRotaryEmbedding`` applies it."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,6 +82,7 @@ class ModelConfig:
     local_window: int = 2048
     pos_kind: str = "rope"           # rope | mrope | learned | none
     rope_theta: float = 10000.0
+    yarn: Optional[YarnConfig] = None    # YaRN-scaled rope (MLA's rope dims)
     mla: Optional[MLAConfig] = None
 
     # --- ffn ---
@@ -162,11 +192,13 @@ class ModelConfig:
             if moe_here:
                 mult = 3 if self.act in ("swiglu", "geglu") else 2
                 e_params = mult * d * self.moe.d_ff_expert
-                n += self.moe.num_experts * e_params
+                n += self.moe.num_held * e_params
                 n += self.moe.num_shared_experts * e_params
                 n += d * self.moe.num_experts        # router
-                if active_only:
-                    n -= (self.moe.num_experts - self.moe.top_k) * e_params
+                if active_only:   # a token's expected experts among those held
+                    m = self.moe
+                    n -= (m.num_held - m.top_k * m.num_held / m.num_experts) \
+                        * e_params
             else:
                 mult = 3 if self.act in ("swiglu", "geglu") else 2
                 n += mult * d * self.d_ff
@@ -227,7 +259,21 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         encoder_seq=16,
         remat=False,
     )
-    if cfg.moe is not None:
+    if cfg.moe is not None and cfg.moe.n_group > 1:
+        # group-limited routing: 16 experts in 4 groups, 2 kept a token,
+        # top-3; a held group stays one group; two layers after the dense
+        m = cfg.moe
+        first = min(m.first_dense_layers, 1)
+        held = None
+        if m.held is not None:
+            g = m.held[0] // (m.num_experts // m.n_group)
+            held = (4 * g, 4 * g + 4)
+        changes["num_layers"] = min(cfg.num_layers, first + 2)
+        changes["moe"] = dataclasses.replace(
+            m, num_experts=16, top_k=3, d_ff_expert=64, n_group=4,
+            topk_group=2, num_shared_experts=min(m.num_shared_experts, 1),
+            first_dense_layers=first, held=held)
+    elif cfg.moe is not None:
         changes["moe"] = dataclasses.replace(
             cfg.moe, num_experts=8, top_k=2, d_ff_expert=64,
             first_dense_layers=min(cfg.moe.first_dense_layers, 1))

@@ -18,7 +18,8 @@ def _ffn_mult(act: str) -> int:
 
 def layer_flops_fwd(cfg, b, s, kind: str, is_moe: bool, kv_len=None) -> float:
     """Forward FLOPs of one layer on (b, s) tokens (implementation counts:
-    full-block attention, capacity-padded MoE, padded-v MLA; causal_skip
+    full-block attention, capacity-padded MoE or a held group's expected
+    pairs, padded-v MLA; causal_skip
     scans only ~(g+1)/(2g) of the KV blocks at g=4 groups)."""
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     t = b * s
@@ -73,7 +74,12 @@ def layer_flops_fwd(cfg, b, s, kind: str, is_moe: bool, kv_len=None) -> float:
     elif is_moe:
         m = cfg.moe
         fl += 2 * t * d * m.num_experts               # router
-        routed_t = t * m.top_k * m.capacity_factor
+        if m.n_group > 1:                             # group maxes and masks
+            fl += t * m.num_experts
+        if m.held is None:
+            routed_t = t * m.top_k * m.capacity_factor
+        else:       # the held experts' expected pairs, none padded or dropped
+            routed_t = t * m.top_k * m.num_held / m.num_experts
         fl += 2 * routed_t * d * m.d_ff_expert * _ffn_mult(cfg.act)
         fl += 2 * t * d * m.d_ff_expert * m.num_shared_experts * _ffn_mult(cfg.act)
     else:

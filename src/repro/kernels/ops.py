@@ -13,7 +13,9 @@ from jax.experimental.layout import Layout
 
 from repro.kernels import coschedule as _cs
 from repro.kernels import decode_attention as _da
+from repro.kernels import expert_ffn as _ef
 from repro.kernels import flash_attention as _fa
+from repro.kernels import ref as _ref
 from repro.kernels import rg_lru as _lru
 from repro.kernels import rwkv6_scan as _wkv
 from repro.kernels import sliced_matmul as _sm
@@ -65,6 +67,29 @@ def decode_attention(q, k_cache, v_cache, layer, t, k_new, v_new):
         q, k_cache, v_cache, layer, t, k_new, v_new,
         positions_minor=cache_positions_minor(k_cache.shape, k_cache.dtype),
         interpret=_default_interpret())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def expert_ffn(xs, wi, wg, wo, tile_expert, n_tiles, layer, tm):
+    """The grouped expert FFN (``kernels/expert_ffn.py``) over ``tm``-row
+    tiles. Its gradient is that of the plain ``ref.expert_ffn``."""
+    return _ef.expert_ffn(xs, wi, wg, wo, tile_expert, n_tiles, layer,
+                          tm=tm, interpret=_default_interpret())
+
+
+def _expert_ffn_fwd(xs, wi, wg, wo, tile_expert, n_tiles, layer, tm):
+    out = expert_ffn(xs, wi, wg, wo, tile_expert, n_tiles, layer, tm)
+    return out, (xs, wi, wg, wo, tile_expert, layer)
+
+
+def _expert_ffn_bwd(tm, res, g):
+    xs, wi, wg, wo, tile_expert, layer = res
+    _, vjp = jax.vjp(lambda *w: _ref.expert_ffn(*w, tile_expert, layer,
+                                                tm=tm), xs, wi, wg, wo)
+    return (*vjp(g), None, None, None)
+
+
+expert_ffn.defvjp(_expert_ffn_fwd, _expert_ffn_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

@@ -77,3 +77,18 @@ def rg_lru(x, a_log, h0=None):
                          (x.astype(jnp.float32).transpose(1, 0, 2),
                           a_log.astype(jnp.float32).transpose(1, 0, 2)))
     return hs.transpose(1, 0, 2)
+
+
+def expert_ffn(xs, wi, wg, wo, tile_expert, layer, *, tm):
+    """Every ``tm``-row tile of xs (M, D) through its expert's SwiGLU FFN,
+    from layer ``layer`` of the stacked wi, wg (L, E, D, F), wo (L, E, F,
+    D): the grouped kernel's rows of the tiles it computes."""
+    m, d = xs.shape
+    x = xs.reshape(m // tm, tm, d)
+    w_i, w_g, w_o = (w[layer][tile_expert] for w in (wi, wg, wo))
+    up = jnp.einsum("tmd,tdf->tmf", x, w_i, preferred_element_type=jnp.float32)
+    gate = jnp.einsum("tmd,tdf->tmf", x, w_g,
+                      preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(gate) * up).astype(wo.dtype)
+    y = jnp.einsum("tmf,tfd->tmd", h, w_o, preferred_element_type=jnp.float32)
+    return y.reshape(m, d).astype(xs.dtype)

@@ -307,8 +307,11 @@ def mla_forward(x, p, cfg, positions, *, causal=True, cache=None, t=None):
     """MLA with compressed KV cache (c_kv + shared k_rope).
 
     Training/prefill: expand K/V from latents and run standard attention.
-    Decode: expand from the cached latents (the cache stores only
-    kv_lora_rank + qk_rope_dim per token — the paper's 93% cache saving).
+    Decode: absorbed, attention runs over the cached latents (the cache
+    stores only kv_lora_rank + qk_rope_dim per token — the paper's 93%
+    cache saving), or expanded from them (``mla_decode="expand"``).
+    With YaRN (``cfg.yarn``) the rope dims take its frequencies, and the
+    softmax scale its temperature squared, folded into the queries.
     """
     m = cfg.mla
     b, s, d = x.shape
@@ -316,16 +319,23 @@ def mla_forward(x, p, cfg, positions, *, causal=True, cache=None, t=None):
 
     # --- queries ---
     q_lat = L.rmsnorm(x @ p["wq_a"], p["q_norm"]["scale"])
-    q = jnp.einsum("bsr,rhk->bshk", q_lat, p["wq_b"])          # (B,S,H,dn+dr)
+    if cfg.yarn is None:
+        q = jnp.einsum("bsr,rhk->bshk", q_lat, p["wq_b"])      # (B,S,H,dn+dr)
+    else:
+        y = cfg.yarn
+        temp = L.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+        q = (jnp.einsum("bsr,rhk->bshk", q_lat, p["wq_b"],
+                        preferred_element_type=jnp.float32)
+             * temp).astype(x.dtype)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta, cfg.yarn)
 
     # --- latent kv ---
     kv_a = x @ p["wkv_a"]                                      # (B,S,r+dr)
     c_kv = L.rmsnorm(kv_a[..., :m.kv_lora_rank], p["kv_norm"]["scale"])
     k_rope = kv_a[..., m.kv_lora_rank:]                        # (B,S,dr) shared
     k_rope = L.apply_rope(k_rope[:, :, None, :], positions,
-                          cfg.rope_theta)[:, :, 0, :]
+                          cfg.rope_theta, cfg.yarn)[:, :, 0, :]
 
     new_cache = None
     if cache is not None:
@@ -342,14 +352,17 @@ def mla_forward(x, p, cfg, positions, *, causal=True, cache=None, t=None):
         c_kv_full, k_rope_full = c_kv, k_rope
         kv_len = None
 
-    # --- expand k/v from latents ---
-    kv = jnp.einsum("bsr,rhk->bshk", c_kv_full.astype(x.dtype), p["wkv_b"])
-    k_nope, vv = kv[..., :dn], kv[..., dn:]
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope_full.astype(x.dtype)[:, :, None, :],
-                                  k_nope.shape[:-1] + (dr,))], axis=-1)
-    qq = jnp.concatenate([q_nope, q_rope], axis=-1)
-    qq, k, vv = _constrain_qkv(qq, k, vv)
+    def expand():
+        """Per-head q, k and v, k and v expanded from the latents."""
+        kv = jnp.einsum("bsr,rhk->bshk", c_kv_full.astype(x.dtype),
+                        p["wkv_b"])
+        k_nope, vv = kv[..., :dn], kv[..., dn:]
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(
+                k_rope_full.astype(x.dtype)[:, :, None, :],
+                k_nope.shape[:-1] + (dr,))], axis=-1)
+        qq = jnp.concatenate([q_nope, q_rope], axis=-1)
+        return _constrain_qkv(qq, k, vv)
 
     if cache is not None and s == 1 and cfg.mla_decode == "absorbed":
         # absorbed decode: attention runs in the latent space — never
@@ -371,8 +384,10 @@ def mla_forward(x, p, cfg, positions, *, causal=True, cache=None, t=None):
         o_lat = jnp.einsum("bhst,btr->bshr", pr, ckv_c.astype(jnp.float32))
         o = jnp.einsum("bshr,rhv->bshv", o_lat.astype(x.dtype), w_v)
     elif cache is not None and s == 1:
+        qq, k, vv = expand()
         o = decode_attention(qq, k, _pad_v(vv, dn + dr), kv_len)[..., :dv]
     else:
+        qq, k, vv = expand()
         blk = _pick_block(s, k.shape[1])
         if s <= 2 * blk:
             o = full_attention(qq, k, _pad_v(vv, dn + dr), causal=causal)[..., :dv]

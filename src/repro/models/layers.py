@@ -83,12 +83,49 @@ def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(scale) + 1."""
+    if scale <= 1:
+        return 1.0
+    return 0.1 * mscale * float(np.log(scale)) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN's rotary frequencies: interpolated by ``yarn.factor`` below
+    the correction range of ``beta_fast``..``beta_slow`` rotations over
+    the original context, extrapolated above it, ramped between."""
+    def dim_of(rotations):
+        return head_dim * np.log(yarn.original_max_position_embeddings
+                                 / (rotations * 2 * np.pi)) \
+            / (2 * np.log(theta))
+
+    low = max(int(np.floor(dim_of(yarn.beta_fast))), 0)
+    high = min(int(np.ceil(dim_of(yarn.beta_slow))), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extra = rope_frequencies(head_dim, theta)
+    inter = extra / np.float32(yarn.factor)
+    keep = 1.0 - ramp                           # 1: extrapolate
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x, positions, theta: float = 10000.0, yarn=None):
+    """x: (..., S, H, D); positions: broadcastable to (..., S). ``yarn``
+    (a ``YarnConfig``) scales the frequencies, and cos and sin by
+    mscale / mscale_all_dim."""
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_frequencies(d, theta))           # (d/2,)
+    if yarn is None:
+        freqs = jnp.asarray(rope_frequencies(d, theta))       # (d/2,)
+    else:
+        freqs = jnp.asarray(yarn_frequencies(d, theta, yarn))
     ang = positions[..., :, None, None].astype(jnp.float32) * freqs  # (...,S,1,d/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        m = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
+            yarn.factor, yarn.mscale_all_dim)
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -5,12 +5,15 @@ dense per-expert einsum -> unpermute). This avoids the GShard (tokens, E, C)
 one-hot, whose memory is quadratic-ish at 256 experts; compute scales with
 tokens*top_k*capacity_factor instead of tokens*E.
 
-Two paths:
+Three paths:
   * ``moe_ffn`` — single logical program; GSPMD shards the expert einsum over
     'model' (E axis) and tokens over 'data'. Collectives are inferred by XLA.
   * ``moe_ffn_ep`` — explicit expert parallelism under shard_map with a
     static-capacity all_to_all (production EP; used by the hillclimbed
     configs).
+  * ``held_moe_ffn`` — one expert-parallel device's share (``moe.held``):
+    every token routed over all experts, the held experts' part computed
+    with no capacity and no drop by the grouped ``expert_ffn`` kernel.
 """
 from __future__ import annotations
 
@@ -18,15 +21,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops
+from repro.kernels.expert_ffn import pick_tile
 from repro.models import layers as L
 
 
 def init_moe(key, cfg, n_layers: int, dtype=jnp.bfloat16):
     m = cfg.moe
-    d, f, e = cfg.d_model, m.d_ff_expert, m.num_experts
+    d, f, e = cfg.d_model, m.d_ff_expert, m.num_held
     ks = jax.random.split(key, 6)
     p = {
-        "router": L.dense_init(ks[0], (d, e), dtype=jnp.float32),
+        "router": L.dense_init(ks[0], (d, m.num_experts), dtype=jnp.float32),
         "wi": L.dense_init(ks[1], (e, d, f), dtype=dtype),
         "wg": L.dense_init(ks[2], (e, d, f), dtype=dtype),
         "wo": L.dense_init(ks[3], (e, f, d),
@@ -39,14 +44,31 @@ def init_moe(key, cfg, n_layers: int, dtype=jnp.bfloat16):
 
 
 def _route(x2d, router_w, m):
-    """x2d: (T, D) -> (top_w, top_i) each (T, k); plus aux loss."""
-    logits = x2d.astype(jnp.float32) @ router_w                # (T, E)
+    """x2d: (T, D) -> (top_w, top_i) each (T, k); plus aux loss.
+
+    With ``m.n_group`` > 1, group-limited greedy (DeepSeek-V2): a group's
+    score is its best expert's, and the top k come from the
+    ``m.topk_group`` best groups. The top-k weights are renormalised
+    (``norm_topk_prob``) or scaled by ``routed_scaling_factor``."""
+    logits = jnp.dot(x2d.astype(jnp.float32), router_w,
+                     precision=jax.lax.Precision.HIGHEST)      # (T, E)
     if getattr(m, "router_act", "softmax") == "sigmoid":
         scores = jax.nn.sigmoid(logits)
     else:
         scores = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = jax.lax.top_k(scores, m.top_k)              # (T, k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    pick = scores
+    if m.n_group > 1:
+        t = scores.shape[0]
+        best = scores.reshape(t, m.n_group, -1).max(-1)        # (T, G)
+        _, top_g = jax.lax.top_k(best, m.topk_group)
+        kept = jax.nn.one_hot(top_g, m.n_group, dtype=bool).any(-2)
+        kept = jnp.repeat(kept, m.num_experts // m.n_group, axis=-1)
+        pick = jnp.where(kept, scores, 0.0)
+    top_w, top_i = jax.lax.top_k(pick, m.top_k)                # (T, k)
+    if m.norm_topk_prob:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    else:
+        top_w = top_w * m.routed_scaling_factor
     # load-balance aux loss (Switch-style)
     probs_mean = jnp.mean(scores, axis=0)                      # (E,)
     counts = jnp.zeros((m.num_experts,), jnp.float32).at[top_i.reshape(-1)].add(1.0)
@@ -125,6 +147,71 @@ def _moe_tokens(x2d, p, cfg):
     w_sorted = top_w.reshape(-1)[sort_idx].astype(ys.dtype)    # (T*k,)
     out = jnp.zeros((t, d), ys.dtype).at[tok_idx].add(ys * w_sorted[:, None])
     return out.astype(x2d.dtype), aux
+
+
+# --------------------------------------------------------------------- #
+# One device's share: held experts, routed over all
+# --------------------------------------------------------------------- #
+def held_moe_ffn(x, p, cfg, *, layer=None):
+    """x: (B, S, D) -> (out, aux_loss): the part of the routed experts'
+    output that the held experts ``cfg.moe.held`` give, plus the shared
+    experts. Every token is routed over all ``num_experts``; each of its
+    (token, expert) pairs whose expert is held is computed, none dropped.
+
+    ``p``'s ``wi``, ``wg``, ``wo`` hold the held experts only: (E_h, D, F)
+    and (E_h, F, D), or, with ``layer`` given, the stage's stacked (L,
+    E_h, ...) weights, read at ``layer`` where they lie."""
+    m = cfg.moe
+    if cfg.act != "swiglu":
+        raise ValueError(f"held experts run SwiGLU, not {cfg.act!r}")
+    b, s, d = x.shape
+    x2d = x.reshape(-1, d)
+    with jax.named_scope("moe_router"):
+        top_w, top_i, aux = _route(x2d, p["router"], m)
+    with jax.named_scope("held_experts"):
+        w = [p[k] if layer is not None else p[k][None]
+             for k in ("wi", "wg", "wo")]
+        out = _held_experts(x2d, top_w, top_i, w,
+                            0 if layer is None else layer, m)
+    if m.num_shared_experts:
+        with jax.named_scope("shared_experts"):
+            out = out + L.mlp(x2d, p["shared"], cfg.act)
+    return out.reshape(b, s, d).astype(x.dtype), aux
+
+
+def _held_experts(x2d, top_w, top_i, w, layer, m):
+    """Sum over each token's held (token, expert) pairs of weight times
+    expert output, (T, D) in f32. Pairs sort by held expert into tiles of
+    one expert each (an expert's last tile padded with zero rows), which the
+    grouped kernel runs; at most every pair is held, a static bound."""
+    t, d = x2d.shape
+    n_held = m.num_held
+    start = 0 if m.held is None else m.held[0]
+    n = t * m.top_k
+    tm = pick_tile(t * m.top_k / m.num_experts)   # an expert's expected rows
+    n_tiles = -(-n // tm) + n_held               # each group's last tile
+    rows = n_tiles * tm
+
+    local = top_i.reshape(-1) - start
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local, n_held)                      # n_held: not here
+    order = jnp.argsort(key)
+    key_s, tok_s = key[order], order // m.top_k
+    counts = jnp.bincount(key, length=n_held + 1)[:n_held]
+    tiles = -(-counts // tm)                                   # per expert
+    first_tile = jnp.cumsum(tiles) - tiles
+    first_pair = jnp.cumsum(counts) - counts
+    k = jnp.minimum(key_s, n_held - 1)
+    row = jnp.where(key_s < n_held,
+                    first_tile[k] * tm + jnp.arange(n) - first_pair[k], rows)
+    xs = jnp.zeros((rows, d), x2d.dtype).at[row].set(x2d[tok_s], mode="drop")
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(jnp.cumsum(tiles), jnp.arange(n_tiles),
+                         side="right"), n_held - 1).astype(jnp.int32)
+    ys = ops.expert_ffn(xs, *w, tile_expert, jnp.sum(tiles), layer, tm)
+    y = ys.at[row].get(mode="fill", fill_value=0).astype(jnp.float32)
+    wt = top_w.reshape(-1)[order][:, None]
+    return jnp.zeros((t, d), jnp.float32).at[tok_s].add(y * wt)
 
 
 # --------------------------------------------------------------------- #

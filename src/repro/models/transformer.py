@@ -180,11 +180,13 @@ def _local_attention_block(x, p, cfg, positions, cache, t):
 
 
 def apply_block(x, bp, cfg, sig, positions, *, enc_out=None, cache=None,
-                t=None, moe_group: int = 0, layer=None):
+                t=None, moe_group: int = 0, layer=None, expert_layer=None):
     """One transformer block. Returns (x, new_cache, aux_loss).
 
     ``layer``: the block's index in its stage, given when ``cache`` holds
-    the stage's whole k and v (``A.reads_cache_in_place``)."""
+    the stage's whole k and v (``A.reads_cache_in_place``);
+    ``expert_layer`` likewise, given when ``bp``'s held experts are the
+    stage's whole stacked weights (``_HELD``)."""
     kind, is_moe = sig
     aux = jnp.zeros((), jnp.float32)
     h = L.norm(x, bp["norm1"], cfg.norm)
@@ -259,7 +261,9 @@ def apply_block(x, bp, cfg, sig, positions, *, enc_out=None, cache=None,
                   and current_layout() == "2d"
                   and "model" in mesh.shape and mesh.shape["model"] > 1
                   and h2.shape[1] % mesh.shape["model"] == 0)
-        if use_ep:
+        if cfg.moe.held is not None:
+            f, aux = M.held_moe_ffn(h2, bp["moe"], cfg, layer=expert_layer)
+        elif use_ep:
             f, aux = M.moe_ffn_ep_sharded(h2, bp["moe"], cfg, mesh)
         else:
             f, aux = M.moe_ffn(h2, bp["moe"], cfg, group_size=moe_group)
@@ -327,6 +331,9 @@ def count_params(params) -> int:
 # ===================================================================== #
 # forward
 # ===================================================================== #
+_HELD = ("wi", "wg", "wo")     # a held-expert layer's kernel-read weights
+
+
 def _run_stages(params, cfg, x, positions, stages, *, prefix="stage",
                 enc_out=None, caches=None, t=None, decode=False,
                 causal=True, moe_group=0, root=None):
@@ -348,8 +355,16 @@ def _run_stages(params, cfg, x, positions, stages, *, prefix="stage",
             xs_cache = {n: {k: a for k, a in c.items()
                             if n not in whole or k not in ("k", "v")}
                         for n, c in cache_s.items()}
+        # sublayers holding experts: their expert weights stay out of the
+        # scan's per-layer slices too, read in place by the kernel
+        held = {f"sub{ci}" for ci, (_, is_moe) in enumerate(st.cycle)
+                if is_moe and cfg.moe.held is not None}
+        xs_params = {n: dict(c, moe={k: a for k, a in c["moe"].items()
+                                     if k not in _HELD})
+                     if n in held else c for n, c in sp.items()}
 
-        def body(carry, xs, _st=st, _whole=whole, _cache_s=cache_s):
+        def body(carry, xs, _st=st, _whole=whole, _cache_s=cache_s,
+                 _held=held, _sp=sp):
             xx = carry
             layer_ps, layer_cs, i = xs
             aux_acc = jnp.zeros((), jnp.float32)
@@ -357,14 +372,20 @@ def _run_stages(params, cfg, x, positions, stages, *, prefix="stage",
             for ci, sig in enumerate(_st.cycle):
                 name = f"sub{ci}"
                 cc = layer_cs.get(name) if layer_cs is not None else None
+                bp = layer_ps[name]
                 layer = None
                 if name in _whole:
                     cc = dict(cc, k=_cache_s[name]["k"], v=_cache_s[name]["v"])
                     layer = i
+                expert_layer = None
+                if name in _held:
+                    bp = dict(bp, moe=dict(bp["moe"], **{
+                        k: _sp[name]["moe"][k] for k in _HELD}))
+                    expert_layer = i
                 xx, nc, aux = apply_block(
-                    xx, layer_ps[name], cfg, sig, positions,
+                    xx, bp, cfg, sig, positions,
                     enc_out=enc_out, cache=cc, t=t, moe_group=moe_group,
-                    layer=layer)
+                    layer=layer, expert_layer=expert_layer)
                 if new_caches is not None:
                     ncs[name] = nc
                 aux_acc = aux_acc + aux
@@ -372,8 +393,8 @@ def _run_stages(params, cfg, x, positions, stages, *, prefix="stage",
 
         if cfg.remat and not decode:
             body = jax.checkpoint(body)
-        layers = jnp.arange(st.repeats) if whole else None
-        x, (ncs, auxs) = jax.lax.scan(body, x, (sp, xs_cache, layers))
+        layers = jnp.arange(st.repeats) if whole or held else None
+        x, (ncs, auxs) = jax.lax.scan(body, x, (xs_params, xs_cache, layers))
         for name in whole:                 # (L, B, 1, kv, hd) at position t
             ncs[name] = dict(ncs[name], **{
                 k: jax.lax.dynamic_update_slice_in_dim(

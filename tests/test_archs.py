@@ -24,7 +24,7 @@ def arch_setup(request):
 
 
 def test_config_registry_complete():
-    assert len(ARCH_IDS) == 10
+    assert len(ARCH_IDS) == 11
     for a in ARCH_IDS:
         cfg = get_config(a)
         assert cfg.name == a

@@ -1,7 +1,9 @@
 """Compiles the main path for a described TPU v5e chip, none attached.
 
-Each Pallas kernel at real widths, and the full-width phi3-mini-3.8b decode
-step with its weights as arguments, go through the chip's own compiler:
+Each Pallas kernel at real widths, the full-width phi3-mini-3.8b decode
+step, and deepseek-v2-236b-ep8's prefill and decode steps at the
+``dsv2.pd-closed`` cell's shapes, with their weights as arguments, go
+through the chip's own compiler:
 what it refuses (unaligned blocks, unlowerable primitives, scoped-VMEM
 overruns, programs larger than HBM) fails here, where interpret mode would
 pass. Kernels are called with ``interpret=False`` directly, because the
@@ -19,6 +21,7 @@ from repro.configs import get_config
 from repro.core.profiles import V5E, device_peaks
 from repro.kernels import coschedule as cs
 from repro.kernels import decode_attention as da
+from repro.kernels import expert_ffn as ef
 from repro.kernels import flash_attention as fa
 from repro.kernels import rg_lru as lru
 from repro.kernels import rwkv6_scan as wkv
@@ -85,12 +88,21 @@ def _kernel_case(name):
                 [((16, h, hd), bf16), cache, cache, ((), jnp.int32),
                  ((), jnp.int32), ((16, kvh, hd), bf16),
                  ((16, kvh, hd), bf16)])
+    if name == "expert_ffn":      # deepseek-v2-236b-ep8's decode step
+        rows, tm = 2176, 32            # 68 tiles of 32: 256 tokens x 6
+        return (lambda x, wi, wg, wo, te, n, layer: ef.expert_ffn(
+                    x, wi, wg, wo, te, n, layer, tm=tm),
+                [((rows, 5120), bf16), ((4, 20, 5120, 1536), bf16),
+                 ((4, 20, 5120, 1536), bf16), ((4, 20, 1536, 5120), bf16),
+                 ((rows // tm,), jnp.int32), ((), jnp.int32),
+                 ((), jnp.int32)])
     raise KeyError(name)
 
 
 @pytest.mark.parametrize("name", ["coschedule", "sliced_matmul",
                                   "flash_attention", "rwkv6_scan", "rg_lru",
-                                  "decode_attention", "decode_attention_gqa"])
+                                  "decode_attention", "decode_attention_gqa",
+                                  "expert_ffn"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _kernel_case(name)
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
@@ -170,3 +182,54 @@ def test_phi3_decode_reads_cache_in_place_on_v5e(one_chip, monkeypatch):
     assert ops_on_cache.count("parameter") == 2
     assert set(ops_on_cache) == {"parameter", "get-tuple-element"}
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_dsv2_ep8_step_reads_experts_in_place_on_v5e(one_chip, monkeypatch,
+                                                     phase):
+    """deepseek-v2-236b-ep8's served steps at the cell's shapes (prefill 2
+    x 512; decode 256 sequences at a 1024 cache) compile for the chip, fit
+    its memory beside their weights, and run the ``expert_ffn`` kernel on
+    each expert layer's held experts where they lie in the stage's
+    stacked weights: nothing slices, copies or relayouts an expert
+    matrix."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg = get_config("deepseek-v2-236b-ep8")
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(T.init_params, cfg),
+                                    jax.random.PRNGKey(0)))
+    if phase == "prefill":
+        args = (params, {"tokens": jax.ShapeDtypeStruct(
+            (2, 512), jnp.int32, sharding=one_chip)})
+        step = functools.partial(serve.prefill_logits, cfg=cfg)
+    else:
+        caches = on_chip(jax.eval_shape(functools.partial(
+            T.init_decode_caches, cfg, 256, 1024)))
+        args = (params, caches,
+                jax.ShapeDtypeStruct((256,), jnp.int32, sharding=one_chip),
+                jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+        step = functools.partial(serve.decode_logits, cfg=cfg)
+    compiled = jax.jit(step).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%expert_ffn\.\d+ = \S+ custom-call", text)) == 1
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 2 * cfg.param_count()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < device_peaks(V5E).hbm_bytes
+    m = cfg.moe
+    shapes = {f"bf16[{','.join(map(str, s))}]" for s in (
+        (m.num_held, cfg.d_model, m.d_ff_expert),
+        (m.num_held, m.d_ff_expert, cfg.d_model),
+        (cfg.num_layers - 1, m.num_held, cfg.d_model, m.d_ff_expert),
+        (cfg.num_layers - 1, m.num_held, m.d_ff_expert, cfg.d_model))}
+    made = re.findall(r"^\s*(?:ROOT )?%\S+ = (bf16\[[\d,]*\])\S* ([\w-]+)\(",
+                      text, re.MULTILINE)
+    ops_on_experts = [op for shape, op in made if shape in shapes]
+    assert ops_on_experts.count("parameter") == 3
+    assert set(ops_on_experts) == {"parameter", "get-tuple-element"}

@@ -104,3 +104,80 @@ def test_kernels_match_model_layers():
     got = ops.rwkv6_scan(r, k, v, w_log, u, chunk=16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-3, rtol=1e-3)
+
+
+def _expert_tiles(sizes, tm, d, dtype, key):
+    """Rows for experts with ``sizes`` rows each, laid out as the held
+    layer lays them out: each expert's rows in whole ``tm``-row tiles
+    (zero-padded), then spare tiles up to a static bound. Returns (xs,
+    tile_expert, tiles in use, each row's expert or -1 for padding)."""
+    tiles = [-(-n // tm) for n in sizes]
+    n_tiles = sum(tiles) + 2                  # two spare tiles, unused
+    xs = np.zeros((n_tiles * tm, d), np.float32)
+    owner = np.full(n_tiles * tm, -1)
+    rows = np.asarray(rand(key, (sum(sizes), d), jnp.float32))
+    tile_expert, r, at = [], 0, 0
+    for e, (n, t) in enumerate(zip(sizes, tiles)):
+        xs[at:at + n] = rows[r:r + n]
+        owner[at:at + n] = e
+        tile_expert += [e] * t
+        r, at = r + n, at + t * tm
+    tile_expert += [len(sizes) - 1] * 2
+    return (jnp.asarray(xs, dtype), jnp.asarray(tile_expert, jnp.int32),
+            sum(tiles), owner)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("sizes", [(0, 5, 0, 3), (37, 0, 0, 0),
+                                   (1, 16, 17, 40), (0, 0, 0, 0)],
+                         ids=["empty-groups", "all-in-one", "ragged",
+                              "no-rows"])
+def test_expert_ffn(sizes, dtype):
+    """The grouped expert FFN against a plain per-expert SwiGLU, on the
+    rows of the tiles in use: experts with no rows, all rows on one expert
+    (over several tiles), ragged counts, and no rows at all."""
+    n_layers, d, f, tm, layer = 2, 128, 256, 16, 1
+    ks = jax.random.split(KEY, 4)
+    wi, wg = (rand(k, (n_layers, len(sizes), d, f), dtype) * 0.1
+              for k in ks[:2])
+    wo = rand(ks[2], (n_layers, len(sizes), f, d), dtype) * 0.1
+    xs, tile_expert, used, owner = _expert_tiles(sizes, tm, d, dtype, ks[3])
+    got = np.asarray(ops.expert_ffn(xs, wi, wg, wo, tile_expert,
+                                    jnp.int32(used), jnp.int32(layer), tm),
+                     np.float32)
+    assert got.shape == xs.shape
+    for e in range(len(sizes)):
+        rows = owner == e
+        x = xs[rows]
+        h = (jax.nn.silu(jnp.dot(x, wg[layer, e],
+                                 preferred_element_type=jnp.float32))
+             * jnp.dot(x, wi[layer, e], preferred_element_type=jnp.float32))
+        want = jnp.dot(h.astype(dtype), wo[layer, e],
+                       preferred_element_type=jnp.float32)
+        np.testing.assert_allclose(got[rows], np.asarray(want, np.float32),
+                                   **tol(dtype))
+    np.testing.assert_allclose(               # and the oracle the VJP uses
+        got[owner >= 0], np.asarray(ref.expert_ffn(
+            xs, wi, wg, wo, tile_expert, layer, tm=tm),
+            np.float32)[owner >= 0], **tol(dtype))
+
+
+def test_expert_ffn_gradient_is_the_oracles():
+    d, f, tm = 128, 128, 16
+    ks = jax.random.split(KEY, 4)
+    wi, wg = (rand(k, (1, 2, d, f), jnp.float32) * 0.1 for k in ks[:2])
+    wo = rand(ks[2], (1, 2, f, d), jnp.float32) * 0.1
+    xs, te, used, owner = _expert_tiles((9, 20), tm, d, jnp.float32, ks[3])
+    live = jnp.asarray(owner >= 0)[:, None]
+
+    def loss(fn, *w):          # rows of tiles not in use are unwritten
+        return jnp.sum(jnp.square(jnp.where(live, fn(*w), 0.0)))
+
+    got = jax.grad(lambda *w: loss(lambda *a: ops.expert_ffn(
+        *a, te, jnp.int32(used), jnp.int32(0), tm), *w),
+        argnums=(0, 1, 2, 3))(xs, wi, wg, wo)
+    want = jax.grad(lambda *w: loss(lambda *a: ref.expert_ffn(
+        *a, te, 0, tm=tm), *w), argnums=(0, 1, 2, 3))(xs, wi, wg, wo)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=1e-5, rtol=1e-5)
